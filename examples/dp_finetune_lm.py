@@ -31,7 +31,7 @@ def main():
         argv += ["--d-model", str(args.d_model)]
     if args.layers:
         argv += ["--layers", str(args.layers)]
-    losses = train_mod.main(argv)
+    losses = train_mod.main(argv).losses
     print(f"loss: {losses[0]:.3f} -> {losses[-1]:.3f} over {args.steps} steps")
 
 

@@ -1,7 +1,7 @@
 """Jaxpr flattening and slicing for the static DP verifier.
 
 ``jax.make_jaxpr`` on the private step yields a ClosedJaxpr whose
-interesting structure hides inside nested call equations (``pjit``,
+interesting structure hides inside nested call equations (``jit``,
 ``custom_jvp_call``, ``remat``).  :func:`flatten` inlines those into one
 topologically ordered node list with variables resolved across call
 boundaries, so the analysis passes walk a single graph.  Control-flow
@@ -15,14 +15,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Iterator, List, Optional, Set, Tuple
 
-try:  # jax >= 0.4.16
-    from jax.extend.core import ClosedJaxpr, Literal, Var
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Literal, Var  # type: ignore
+from jax.extend.core import ClosedJaxpr, Literal, Var
 
 # Call-like primitives whose body is semantically "run once, in place":
 # safe to inline into the parent graph.
-INLINE_PRIMS = ("pjit", "closed_call", "core_call", "call",
+INLINE_PRIMS = ("jit", "closed_call", "core_call", "call",
                 "custom_jvp_call", "custom_vjp_call",
                 "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
                 "remat", "remat2", "checkpoint")

@@ -33,11 +33,6 @@ from typing import List, Optional
 from repro.analysis.graph import FlatGraph
 from repro.analysis.report import Finding
 
-try:
-    from jax.sharding import NamedSharding, PartitionSpec
-except Exception:  # pragma: no cover - jax always present in this repo
-    NamedSharding = PartitionSpec = None  # type: ignore
-
 DATA_AXIS_NAMES = ("data", "pod", "batch", "dp", "fsdp")
 
 

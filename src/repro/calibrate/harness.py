@@ -104,8 +104,9 @@ def measure_collective_bytes_per_second(axis: str, size: int, *,
 def sweep_pe_conv_vmem(*, quick: bool = False,
                        budgets=VMEM_SWEEP) -> dict:
     """The pending ``VMEM_BUDGET`` sweep: time ``pe_conv_grad`` under
-    each candidate budget's autotuned output-channel tile and report the
-    winner.  Budgets that resolve to the same tile share one timing."""
+    each candidate budget's autotuned output-channel and row tiles and
+    report the winner.  Budgets that resolve to the same tiles share one
+    timing."""
     from repro.kernels import ops as kops
 
     B, C, D, HW, K = (2, 8, 16, 12, 3) if quick else (4, 16, 32, 16, 3)
@@ -113,16 +114,20 @@ def sweep_pe_conv_vmem(*, quick: bool = False,
     x = jnp.asarray(rng.randn(B, C, HW, HW), jnp.float32)
     out_sp = HW - K + 1
     dy = jnp.asarray(rng.randn(B, D, out_sp, out_sp), jnp.float32)
-    by_bd: dict[int, float] = {}
+    by_tiles: dict[tuple, float] = {}
     sweep: dict[str, dict] = {}
     for budget in budgets:
         bd = kops._autotune_bd(D, C, (HW, HW), (out_sp, out_sp), (K, K),
                                budget)
-        if bd not in by_bd:
-            f = jax.jit(lambda a, b, _bd=bd: kops._pc.pe_conv_grad_2d(
-                a, b, KH=K, KW=K, bd=_bd, interpret=not kops.on_tpu()))
-            by_bd[bd] = _time(f, x, dy, iters=2 if quick else 3)
-        sweep[str(budget)] = {"bd": bd, "seconds": by_bd[bd]}
+        th = kops._pc.row_tile(bd, C, out_sp, HW, K, K, budget)
+        if (bd, th) not in by_tiles:
+            f = jax.jit(lambda a, b, _bd=bd, _th=th:
+                        kops._pc.pe_conv_grad_2d(
+                            a, b, KH=K, KW=K, bd=_bd, th=_th,
+                            interpret=not kops.on_tpu()))
+            by_tiles[bd, th] = _time(f, x, dy, iters=2 if quick else 3)
+        sweep[str(budget)] = {"bd": bd, "th": th,
+                              "seconds": by_tiles[bd, th]}
     winner = min(sweep, key=lambda k: sweep[k]["seconds"])
     return {"vmem_budget": int(winner), "bd": sweep[winner]["bd"],
             "sweep": sweep}

@@ -151,9 +151,9 @@ def dense_norm_and_contrib(meta: LayerMeta, cap, dy, w, *,
     """Fused phase: per-example squared norms *and* the weighted sum
     Σ_b w_b·g_b in one pass over (x, δy).
 
-    ``method="pallas"`` routes through the VMEM-resident fused kernel (the
-    contribution is accumulated from the same tiles the Gram norm already
-    holds, so x/δy are read from HBM once).  ``method="stream"`` is the
+    ``method="pallas"`` routes through the fused kernel (each example's
+    gradient tile is formed in VMEM and feeds both the norm and the
+    contribution; it never reaches HBM).  ``method="stream"`` is the
     materializing realization: per-example grads are formed once and serve
     both reductions — this is what the planner's ``stash`` path exploits.
     Requires the weights to be known entering the pass (bk phase 2,

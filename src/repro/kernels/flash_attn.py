@@ -26,12 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is only importable where TPU lowering exists; interpret-safe
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.kernels.mxu import nn, nt, tn
 
 NEG = -1e30
 
@@ -60,26 +57,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     q = q_ref[0, 0]                       # (bq, hd)
     k = k_ref[0, 0]                       # (bk, hd)
     v = v_ref[0, 0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = nt(q, k) * scale
 
     if causal:
         s = _causal_mask(s, i, j, bq, bk)
 
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    # Row statistics stay (bq, 1) columns: the TPU lays 2-D values out in
+    # (8, 128) tiles, and a (bq,) vector would need a relayout.
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_new = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + \
-        jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[:, 0] = m_new
-    l_scr[:, 0] = l_new
+    p = jnp.exp(s - m_new)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + nn(p.astype(v.dtype), v)
+    m_scr[...] = m_new
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = jnp.maximum(l_scr[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[:, 0] + jnp.log(l)).astype(lse_ref.dtype)
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -95,14 +92,12 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0]
     v = v_ref[0, 0]
     do = do_ref[0, 0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = nt(q, k) * scale
     if causal:
         s = _causal_mask(s, i, j, bq, bk)
-    p = jnp.exp(s - lse_ref[0, 0][:, None])
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-    dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
-                           preferred_element_type=jnp.float32)
+    p = jnp.exp(s - lse_ref[0, 0])
+    ds = p * (nt(do, v) - delta_ref[0, 0]) * scale
+    dq_scr[...] += nn(ds.astype(k.dtype), k)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
@@ -124,16 +119,13 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0]
     v = v_ref[0, 0]
     do = do_ref[0, 0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = nt(q, k) * scale
     if causal:
         s = _causal_mask(s, i, jk, bq, bk)
-    p = jnp.exp(s - lse_ref[0, 0][:, None])
-    dv_scr[...] += jnp.dot(p.astype(do.dtype).T, do,
-                           preferred_element_type=jnp.float32)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0, 0][:, None]) * scale
-    dk_scr[...] += jnp.dot(ds.astype(q.dtype).T, q,
-                           preferred_element_type=jnp.float32)
+    p = jnp.exp(s - lse_ref[0, 0])
+    dv_scr[...] += tn(p.astype(do.dtype), do)
+    ds = p * (nt(do, v) - delta_ref[0, 0]) * scale
+    dk_scr[...] += tn(ds.astype(q.dtype), q)
 
     @pl.when(t == pl.num_programs(3) - 1)
     def _finish():
@@ -142,7 +134,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fwd_call(q, k, v, causal, bq, bk, interpret):
-    """(o, lse) on (B,T,H,hd)/(B,Hkv,S,hd) inputs; lse is (B,H,T) f32."""
+    """(o, lse) on (B,T,H,hd)/(B,S,Hkv,hd) inputs; lse is (B,H,T,1) f32."""
     B, T, H, hd = q.shape
     S = k.shape[1]
     rep = H // k.shape[2]
@@ -151,11 +143,9 @@ def _fwd_call(q, k, v, causal, bq, bk, interpret):
     kt = jnp.moveaxis(k, 2, 1)            # (B,Hkv,S,hd)
     vt = jnp.moveaxis(v, 2, 1)
 
-    if _VMEM is not None:
-        scratch = [_VMEM((bq, 1), jnp.float32), _VMEM((bq, 1), jnp.float32),
-                   _VMEM((bq, hd), jnp.float32)]
-    else:  # pragma: no cover
-        scratch = [pl.MemorySpace.ANY] * 3
+    scratch = [pltpu.VMEM((bq, 1), jnp.float32),
+               pltpu.VMEM((bq, 1), jnp.float32),
+               pltpu.VMEM((bq, hd), jnp.float32)]
 
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, bq=bq, bk=bk,
@@ -170,10 +160,10 @@ def _fwd_call(q, k, v, causal, bq, bk, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, T), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32)],
         scratch_shapes=scratch,
         interpret=interpret,
     )(qt, kt, vt)
@@ -193,20 +183,17 @@ def _bwd_call(q, k, v, o, lse, do, causal, bq, bk, interpret):
     dot = jnp.moveaxis(do, 2, 1)          # (B,H,T,hd)
     # D_i = rowsum(dO ∘ O): the softmax-jacobian correction, cheap in XLA.
     delta = jnp.sum(dot.astype(jnp.float32)
-                    * jnp.moveaxis(o, 2, 1).astype(jnp.float32), axis=-1)
+                    * jnp.moveaxis(o, 2, 1).astype(jnp.float32), axis=-1,
+                    keepdims=True)
 
-    if _VMEM is not None:
-        dq_scr = [_VMEM((bq, hd), jnp.float32)]
-        dkv_scr = [_VMEM((bk, hd), jnp.float32),
-                   _VMEM((bk, hd), jnp.float32)]
-    else:  # pragma: no cover
-        dq_scr = [pl.MemorySpace.ANY]
-        dkv_scr = [pl.MemorySpace.ANY] * 2
+    dq_scr = [pltpu.VMEM((bq, hd), jnp.float32)]
+    dkv_scr = [pltpu.VMEM((bk, hd), jnp.float32),
+               pltpu.VMEM((bk, hd), jnp.float32)]
 
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, hd),
                            lambda b, h, i, j, rep=rep: (b, h // rep, j, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, bq=bq, bk=bk,
                           causal=causal),
@@ -224,7 +211,7 @@ def _bwd_call(q, k, v, o, lse, do, causal, bq, bk, interpret):
         return (b, hkv * rep + t // n_tq, t % n_tq, 0)
 
     def _rows(b, hkv, jk, t, rep=rep, n_tq=n_tq):
-        return (b, hkv * rep + t // n_tq, t % n_tq)
+        return (b, hkv * rep + t // n_tq, t % n_tq, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, scale=scale, bq=bq, bk=bk,
@@ -235,8 +222,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, bq, bk, interpret):
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, jk, t: (b, h, jk, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, jk, t: (b, h, jk, 0)),
             pl.BlockSpec((1, 1, bq, hd), _qi),
-            pl.BlockSpec((1, 1, bq), _rows),
-            pl.BlockSpec((1, 1, bq), _rows),
+            pl.BlockSpec((1, 1, bq, 1), _rows),
+            pl.BlockSpec((1, 1, bq, 1), _rows),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, jk, t: (b, h, jk, 0)),

@@ -1,6 +1,6 @@
-"""Pallas TPU kernel: per-example ghost-norm Gram reduction.
+"""Pallas TPU kernels: per-example ghost norms and the fused norm+contribution.
 
-Computes, per example b,
+``gram_norm`` computes, per example b,
 
     out[b] = Σ_{t,t'} (x_{b,t}·x_{b,t'}) (δy_{b,t}·δy_{b,t'})   [+ bias term]
 
@@ -8,13 +8,16 @@ i.e. ‖δy_bᵀ x_b‖²_F without materializing either the per-example gradien
 (T·Din·Dout) or the full (T,T) Gram matrices in HBM.  XLA realizes the same
 contraction as two (B,T,T) batched matmuls with an HBM round-trip between
 them; here the (bt × bt) Gram tiles live only in VMEM and feed the MXU
-twice per tile pair.
+twice per tile pair.  Grid (B, T/bt, T/bt); each example's (1, 1) output
+tile is revisited across the two inner grid dims and accumulated in place.
 
-Grid: (B, T/bt, T/bt); the output block (1,) is revisited across the two
-inner (sequential) grid dims and accumulated in place.
-
-A token-mask variant (for embedding-gather norms) multiplies the δy-Gram
-tile by [ids_t == ids_{t'}] instead of an x-Gram.
+``gram_norm_fused`` returns the norms *and* the weighted contribution
+Σ_b w_b x_bᵀ δy_b.  It tiles the (Din, Dout) contribution: grid
+(Din/tdi, Dout/tdo, B, T/bt), and each example's gradient tile
+x_b[:, di]ᵀ δy_b[:, do] is accumulated over T in VMEM.  That tile is what
+the contribution needs anyway (Σ_b w_b · tile), and the squared norm is the
+sum over tiles of ‖tile‖²_F — so the norm costs no matmul beyond the
+contribution's own, and the per-example gradient never reaches HBM.
 """
 from __future__ import annotations
 
@@ -23,8 +26,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.mxu import nt, tn
 
 DEFAULT_BT = 256
+# VMEM the row tiles of one grid step may take, double-buffering
+# included: under the 16 MiB a v5e kernel may scope by default.
+VMEM_TILE_BUDGET = 12 << 20
+# Largest contribution tile edge (f32 tile of 1 MiB at 512 x 512).
+MAX_FEATURE_TILE = 512
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _sum11(a):
+    return jnp.sum(a, axis=(0, 1), keepdims=True)
+
+
+def _row_tile(T: int, bt: int, widths: int, itemsize: int) -> int:
+    """The T tile: at most ``bt`` and T rounded up to 8 rows, halved until
+    the four double-buffered (bt, width) input tiles fit the budget."""
+    bt = min(bt, _round_up(T, 8))
+    while bt > 8 and 4 * bt * widths * itemsize > VMEM_TILE_BUDGET:
+        bt = _round_up(bt // 2, 8)
+    return bt
+
+
+def _pad_axis(a, axis: int, size: int):
+    pad = size - a.shape[axis]
+    if not pad:
+        return a
+    cfg = [(0, 0)] * a.ndim
+    cfg[axis] = (0, pad)
+    return jnp.pad(a, cfg)
 
 
 def _gram_kernel(x_i, x_j, y_i, y_j, o_ref, *, has_bias: bool):
@@ -34,34 +71,12 @@ def _gram_kernel(x_i, x_j, y_i, y_j, o_ref, *, has_bias: bool):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    gx = jnp.dot(x_i[0], x_j[0].T, preferred_element_type=jnp.float32)
-    gy = jnp.dot(y_i[0], y_j[0].T, preferred_element_type=jnp.float32)
-    acc = jnp.sum(gx * gy)
+    gx = nt(x_i[0], x_j[0])
+    gy = nt(y_i[0], y_j[0])
+    prod = gx * gy
     if has_bias:
-        acc = acc + jnp.sum(gy)
-    o_ref[0] += acc
-
-
-def _gram_tokmask_kernel(ids_i, ids_j, y_i, y_j, o_ref):
-    i, j = pl.program_id(1), pl.program_id(2)
-
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    gy = jnp.dot(y_i[0], y_j[0].T, preferred_element_type=jnp.float32)
-    mask = (ids_i[0][:, None] == ids_j[0][None, :])
-    o_ref[0] += jnp.sum(jnp.where(mask, gy, 0.0))
-
-
-def _pad_t(a, bt):
-    T = a.shape[1]
-    pad = (-T) % bt
-    if pad:
-        cfg = [(0, 0)] * a.ndim
-        cfg[1] = (0, pad)
-        a = jnp.pad(a, cfg)
-    return a
+        prod = prod + gy
+    o_ref[0] += _sum11(prod)
 
 
 @functools.partial(jax.jit,
@@ -71,124 +86,115 @@ def gram_norm(x, dy, *, has_bias: bool = False, bt: int = DEFAULT_BT,
     """x (B,T,Din), dy (B,T,Dout) -> (B,) fp32 squared per-example norms."""
     B, T, Di = x.shape
     Do = dy.shape[-1]
-    bt = min(bt, max(8, 1 << (T - 1).bit_length()))
-    x, dy = _pad_t(x, bt), _pad_t(dy, bt)
-    Tp = x.shape[1]
-    grid = (B, Tp // bt, Tp // bt)
-    return pl.pallas_call(
+    bt = _row_tile(T, bt, _round_up(Di, 128) + _round_up(Do, 128),
+                   x.dtype.itemsize)
+    Tp = _round_up(T, bt)
+    x, dy = _pad_axis(x, 1, Tp), _pad_axis(dy, 1, Tp)
+    out = pl.pallas_call(
         functools.partial(_gram_kernel, has_bias=has_bias),
-        grid=grid,
+        grid=(B, Tp // bt, Tp // bt),
         in_specs=[
             pl.BlockSpec((1, bt, Di), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bt, Di), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bt, Do), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bt, Do), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, i, j: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
         interpret=interpret,
     )(x, x, dy, dy)
+    return out[:, 0, 0]
 
 
-def _gram_fused_kernel(x_i, x_j, y_i, y_j, w_ref, n_ref, c_ref, cb_ref, *,
-                       has_bias: bool):
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+def _feature_tile(D: int) -> tuple[int, int]:
+    """(tile, padded D): the whole dim when it is small, else a lane-aligned
+    tile of at most MAX_FEATURE_TILE over D padded to 128."""
+    if D <= MAX_FEATURE_TILE:
+        return D, D
+    Dp = _round_up(D, 128)
+    tile = next(t for t in range(MAX_FEATURE_TILE, 0, -128) if Dp % t == 0)
+    return tile, Dp
 
-    @pl.when((b == 0) & (i == 0) & (j == 0))
+
+def _fused_kernel(w_ref, x_ref, y_ref, n_ref, c_ref, cb_ref, g_scr, gb_scr,
+                  *, has_bias: bool):
+    di, b, t = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when((b == 0) & (t == 0))
     def _init_contrib():
         c_ref[...] = jnp.zeros_like(c_ref)
         cb_ref[...] = jnp.zeros_like(cb_ref)
 
-    @pl.when((i == 0) & (j == 0))
-    def _init_norm():
-        n_ref[...] = jnp.zeros_like(n_ref)
+    @pl.when(t == 0)
+    def _init_example():
+        g_scr[...] = jnp.zeros_like(g_scr)
+        gb_scr[...] = jnp.zeros_like(gb_scr)
 
-    gx = jnp.dot(x_i[0], x_j[0].T, preferred_element_type=jnp.float32)
-    gy = jnp.dot(y_i[0], y_j[0].T, preferred_element_type=jnp.float32)
-    acc = jnp.sum(gx * gy)
+    y = y_ref[0]
+    g_scr[...] += tn(x_ref[0], y)
     if has_bias:
-        acc = acc + jnp.sum(gy)
-    n_ref[0] += acc
+        gb_scr[...] += jnp.sum(y.astype(jnp.float32), axis=0, keepdims=True)
 
-    # The contribution Σ_b w_b x_bᵀ δy_b needs each row tile once: fold it
-    # into the j == 0 visit, where x_i / y_i are already VMEM-resident.
-    @pl.when(j == 0)
-    def _contrib():
-        w = w_ref[0]
-        c_ref[...] += w * jnp.dot(x_i[0].T, y_i[0],
-                                  preferred_element_type=jnp.float32)
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _finish():
+        w = w_ref[b]
+        g = g_scr[...]
+        sq = _sum11(g * g)
+        c_ref[...] += w * g
         if has_bias:
-            cb_ref[...] += w * jnp.sum(y_i[0], axis=0)
+            # The bias gradient is the same for every Din tile: count it
+            # on the first.
+            gb = jnp.where(di == 0, gb_scr[...], 0.0)
+            sq = sq + _sum11(gb * gb)
+            cb_ref[0] += w * gb
+        n_ref[0, 0, 0] = sq
 
 
 @functools.partial(jax.jit, static_argnames=("has_bias", "bt", "interpret"))
 def gram_norm_fused(x, dy, w, *, has_bias: bool = False,
                     bt: int = DEFAULT_BT, interpret: bool = True):
-    """Fused ghost-norm + weighted contribution in one VMEM-resident pass.
+    """Fused per-example norm + weighted contribution in one pass.
 
     x (B,T,Din), dy (B,T,Dout), w (B,) ->
         norms_sq (B,) fp32, contrib (Din,Dout) = Σ_b w_b·x_bᵀδy_b fp32,
         bias contrib (Dout,) = Σ_b w_b·Σ_t δy_bt (zeros unless has_bias).
 
-    The norm's (bt×bt) Gram tiles and the contribution's row tiles share
-    the same x/δy loads, so both outputs cost one HBM read of the inputs.
     Requires the weights to be known entering the pass — i.e. the
     book-keeping sum phase, stale-coefficient pipelines, or per-layer
     clipping (where a layer's coefficient depends only on its own norm).
     """
     B, T, Di = x.shape
     Do = dy.shape[-1]
-    bt = min(bt, max(8, 1 << (T - 1).bit_length()))
-    x, dy = _pad_t(x, bt), _pad_t(dy, bt)
-    Tp = x.shape[1]
-    grid = (B, Tp // bt, Tp // bt)
-    return pl.pallas_call(
-        functools.partial(_gram_fused_kernel, has_bias=has_bias),
-        grid=grid,
+    tdi, Dip = _feature_tile(Di)
+    tdo, Dop = _feature_tile(Do)
+    bt = _row_tile(T, bt, (_round_up(tdi, 128) + _round_up(tdo, 128)) // 2,
+                   x.dtype.itemsize)
+    Tp = _round_up(T, bt)
+    x = _pad_axis(_pad_axis(x, 1, Tp), 2, Dip)
+    dy = _pad_axis(_pad_axis(dy, 1, Tp), 2, Dop)
+    n_di, n_do = Dip // tdi, Dop // tdo
+    norms, contrib, cb = pl.pallas_call(
+        functools.partial(_fused_kernel, has_bias=has_bias),
+        grid=(n_di, n_do, B, Tp // bt),
         in_specs=[
-            pl.BlockSpec((1, bt, Di), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bt, Di), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bt, Do), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bt, Do), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1,), lambda b, i, j: (b,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bt, tdi), lambda i, o, b, t: (b, t, i)),
+            pl.BlockSpec((1, bt, tdo), lambda i, o, b, t: (b, t, o)),
         ],
         out_specs=[
-            pl.BlockSpec((1,), lambda b, i, j: (b,)),
-            pl.BlockSpec((Di, Do), lambda b, i, j: (0, 0)),
-            pl.BlockSpec((Do,), lambda b, i, j: (0,)),
+            pl.BlockSpec((1, 1, 1, 1, 1),
+                         lambda i, o, b, t: (i, o, b, 0, 0)),
+            pl.BlockSpec((tdi, tdo), lambda i, o, b, t: (i, o)),
+            pl.BlockSpec((1, 1, tdo), lambda i, o, b, t: (i, 0, o)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((Di, Do), jnp.float32),
-            jax.ShapeDtypeStruct((Do,), jnp.float32),
+            jax.ShapeDtypeStruct((n_di, n_do, B, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Dip, Dop), jnp.float32),
+            jax.ShapeDtypeStruct((n_di, 1, Dop), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((tdi, tdo), jnp.float32),
+                        pltpu.VMEM((1, tdo), jnp.float32)],
         interpret=interpret,
-    )(x, x, dy, dy, w.astype(jnp.float32))
-
-
-@functools.partial(jax.jit, static_argnames=("bt", "interpret"))
-def gram_norm_tokmask(ids, dy, *, bt: int = DEFAULT_BT,
-                      interpret: bool = True):
-    """Embedding-gather ghost norm: out[b] = Σ_{t,t'} [id_t==id_t'] δy·δy."""
-    B, T = ids.shape
-    Do = dy.shape[-1]
-    bt = min(bt, max(8, 1 << (T - 1).bit_length()))
-    pad = (-T) % bt
-    if pad:
-        ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
-        dy = jnp.pad(dy, ((0, 0), (0, pad), (0, 0)))
-    Tp = ids.shape[1]
-    grid = (B, Tp // bt, Tp // bt)
-    return pl.pallas_call(
-        _gram_tokmask_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bt), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bt), lambda b, i, j: (b, j)),
-            pl.BlockSpec((1, bt, Do), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bt, Do), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda b, i, j: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
-        interpret=interpret,
-    )(ids, ids, dy, dy)
+    )(w.astype(jnp.float32), x, dy)
+    return (jnp.sum(norms, axis=(0, 1))[:, 0, 0], contrib[:Di, :Do],
+            cb[0, 0, :Do])

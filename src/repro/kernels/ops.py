@@ -1,9 +1,9 @@
 """jit'd public wrappers + platform dispatch for the Pallas kernels.
 
-On TPU the kernels lower natively; elsewhere (this CPU container, and the
-multi-pod dry-run on the host platform) ``interpret=True`` executes the
-kernel body for correctness, or the pure-jnp reference is used where the
-interpreter would be too slow.  ``use_pallas()`` centralizes the decision.
+On TPU the kernels are compiled; on the CPU (tests, and the multi-device
+dry-run on the host platform) ``interpret=True`` executes the kernel body
+for correctness, or the pure-jnp reference is used where the interpreter
+would be too slow.  ``on_tpu()`` centralizes the decision.
 """
 from __future__ import annotations
 
@@ -18,8 +18,9 @@ from repro.kernels import gram_norm as _gn
 from repro.kernels import pe_conv_grad as _pc
 from repro.kernels import ref as _ref
 
-# Per-core VMEM the pe_conv_grad autotuner plans against: half of a TPU
-# core's ~16 MiB, leaving room for the pipeline's double-buffering.
+# VMEM the pe_conv_grad autotuner plans one grid step against (padded
+# tiles, double-buffering included): half of the 16 MiB a v5e kernel may
+# scope by default.
 # The *analytic* default — vmem_budget() prefers the measured sweep
 # winner from a registered calibration, and REPRO_VMEM_BUDGET overrides
 # both.
@@ -54,18 +55,16 @@ def vmem_budget() -> int:
 
 
 def gram_norm(x, dy, *, has_bias: bool = False, bt: int = 256):
-    if on_tpu():
-        return _gn.gram_norm(x, dy, has_bias=has_bias, bt=bt,
-                             interpret=False)
-    return _gn.gram_norm(x, dy, has_bias=has_bias, bt=bt, interpret=True)
+    return _gn.gram_norm(x, dy, has_bias=has_bias, bt=bt,
+                         interpret=not on_tpu())
 
 
 def gram_norm_fused(x, dy, w, *, has_bias: bool = False, bt: int = 256):
     """Fused ghost-norm + weighted contribution (see gram_norm.py).
 
-    On TPU the Pallas kernel keeps the Gram tiles and the contribution
-    accumulator VMEM-resident (one HBM read of x/δy serves both
-    outputs); elsewhere the pure-jnp reference realizes the same
+    On TPU the Pallas kernel forms each example's gradient tile in VMEM
+    and feeds both outputs from it; elsewhere the pure-jnp reference
+    realizes the same
     contract — the interpreter would dominate any wall-clock the fused
     path is supposed to save (kernel/ref agreement is pinned in
     tests/test_kernels.py)."""
@@ -75,36 +74,47 @@ def gram_norm_fused(x, dy, w, *, has_bias: bool = False, bt: int = 256):
     return _ref.gram_norm_fused_ref(x, dy, w, has_bias=has_bias)
 
 
-def gram_norm_tokmask(ids, dy, *, bt: int = 256):
-    return _gn.gram_norm_tokmask(ids, dy, bt=bt, interpret=not on_tpu())
+def _legal_bds(D: int) -> list:
+    """Output-channel tiles the kernel's blocks allow, largest first: D
+    itself, or a multiple of 8 dividing D."""
+    return [d for d in range(D, 0, -1)
+            if D % d == 0 and (d == D or d % 8 == 0)]
+
+
+def _geometry(x_spatial: tuple, dy_spatial: tuple, k_spatial: tuple):
+    """(W, H', KH, KW) of the kernel's flattened layout; a 1-D conv is the
+    2-D kernel with W = 1."""
+    if len(k_spatial) == 1:
+        return 1, dy_spatial[0], k_spatial[0], 1
+    return x_spatial[1], dy_spatial[0], k_spatial[0], k_spatial[1]
 
 
 @functools.lru_cache(maxsize=256)
 def _autotune_bd(D: int, C: int, x_spatial: tuple, dy_spatial: tuple,
                  k_spatial: tuple, budget: int = VMEM_BUDGET) -> int:
-    """Output-channel tile for the pe_conv_grad grid: the largest divisor
-    of D whose VMEM working set — the full x block, the (bd, spatial') δy
-    tile and the (bd, C, K) output tile — fits the budget."""
-    import math
-    x_elems = C * math.prod(x_spatial)
-    per_row = math.prod(dy_spatial) + C * math.prod(k_spatial)
-    divisors = sorted((d for d in range(1, D + 1) if D % d == 0),
-                      reverse=True)
-    for bd in divisors:
-        if 4 * (x_elems + bd * per_row) <= budget:
+    """Output-channel tile for the pe_conv_grad grid: the largest legal
+    tile whose padded VMEM working set (``pe_conv_grad.vmem_bytes``, at
+    the row tile that then fits) stays within the budget; the smallest
+    legal tile when none does."""
+    W, Hp, KH, KW = _geometry(x_spatial, dy_spatial, k_spatial)
+    bds = _legal_bds(D)
+    for bd in bds:
+        th = _pc.row_tile(bd, C, Hp, W, KH, KW, budget)
+        if _pc.vmem_bytes(bd, C, W, th, KH, KW) <= budget:
             return bd
-    return 1
+    return bds[-1]
 
 
 def pick_bd(D: int, C: int, x_spatial: tuple, dy_spatial: tuple,
             k_spatial: tuple, budget: int = VMEM_BUDGET) -> int:
     """Analytic bd choice, overridable with REPRO_PE_CONV_BD (rounded down
-    to a divisor of D so the kernel's tiling invariant holds).  The env
-    var is read here, outside the cache, so mid-process sweeps work."""
+    to a legal tile, see ``_legal_bds``).  The env var is read here,
+    outside the cache, so mid-process sweeps work."""
     env = os.environ.get("REPRO_PE_CONV_BD")
     if env:
         want = max(1, min(int(env), D))
-        return max(d for d in range(1, want + 1) if D % d == 0)
+        legal = _legal_bds(D)
+        return next((d for d in legal if d <= want), legal[-1])
     return _autotune_bd(D, C, x_spatial, dy_spatial, k_spatial, budget)
 
 
@@ -128,14 +138,16 @@ def pe_conv_grad(x, dy, *, kernel_spatial, stride=1, dilation=1, padding=0,
         if any(p):
             cfg = [(0, 0), (0, 0)] + [(pi, pi) for pi in p]
             x = jnp.pad(x, cfg)
-        bd = pick_bd(dy.shape[1], x.shape[1], tuple(x.shape[2:]),
-                     tuple(dy.shape[2:]), tuple(kernel_spatial),
-                     budget=vmem_budget())
+        budget = vmem_budget()
+        x_sp, dy_sp = tuple(x.shape[2:]), tuple(dy.shape[2:])
+        C, k_sp = x.shape[1], tuple(kernel_spatial)
+        bd = pick_bd(dy.shape[1], C, x_sp, dy_sp, k_sp, budget=budget)
+        W, Hp, KH, KW = _geometry(x_sp, dy_sp, k_sp)
+        th = _pc.row_tile(bd, C, Hp, W, KH, KW, budget)
         if rank == 1:
-            return _pc.pe_conv_grad_1d(x, dy, K=kernel_spatial[0], bd=bd,
+            return _pc.pe_conv_grad_1d(x, dy, K=KH, bd=bd, th=th,
                                        interpret=interp)
-        return _pc.pe_conv_grad_2d(x, dy, KH=kernel_spatial[0],
-                                   KW=kernel_spatial[1], bd=bd,
+        return _pc.pe_conv_grad_2d(x, dy, KH=KH, KW=KW, bd=bd, th=th,
                                    interpret=interp)
     return convops.pe_conv_grad(x, dy, kernel_spatial=kernel_spatial,
                                 stride=stride, dilation=dilation,

@@ -39,13 +39,6 @@ def gram_norm_fused_ref(x, dy, w, *, has_bias: bool = False):
     return n, c, cb
 
 
-def gram_norm_tokmask_ref(ids, dy):
-    dyf = dy.astype(jnp.float32)
-    sy = jnp.einsum("btd,bsd->bts", dyf, dyf)
-    m = (ids[:, :, None] == ids[:, None, :]).astype(jnp.float32)
-    return jnp.einsum("bts,bts->b", m, sy)
-
-
 def pe_conv_grad_1d_ref(x, dy, K: int):
     """Brute-force: δh[b,d,c,k] = Σ_t x[b,c,t+k] dy[b,d,t]."""
     B, C, T = x.shape

@@ -13,6 +13,14 @@ import os
 import jax
 
 
+def make_auto_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the engine's shardings
+    are written for the compiler to propagate, not for explicit-sharding
+    typing (which ``jax.make_mesh`` defaults to)."""
+    return jax.make_mesh(tuple(shape), tuple(names), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -24,15 +32,15 @@ def make_production_mesh(*, multi_pod: bool = False):
             "the dry-run launcher must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_auto_mesh(shape, axes, devices=devices)
 
 
 def make_host_mesh(model_par: int = 1):
     """Small mesh over whatever devices exist (tests, CPU training)."""
     n = len(jax.devices())
     data = n // model_par
-    return jax.make_mesh((data, model_par), ("data", "model"),
-                         devices=jax.devices()[: data * model_par])
+    return make_auto_mesh((data, model_par), ("data", "model"),
+                          devices=jax.devices()[: data * model_par])
 
 
 def force_host_device_count_for(argv):
@@ -83,4 +91,4 @@ def make_mesh_from_spec(spec: str):
             f"{len(jax.devices())} — on CPU hosts set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
             "any jax import")
-    return jax.make_mesh(shape, names, devices=jax.devices()[:n])
+    return make_auto_mesh(shape, names, devices=jax.devices()[:n])

@@ -1,8 +1,8 @@
 """End-to-end DP training driver with checkpoint/restart fault tolerance.
 
-Runs on whatever devices exist (CPU here, a pod in production — the same
-code path: the mesh is just bigger).  The loop is plan → step → account:
-one PrivacyEngine owns the ExecPlan, the jitted private step, and the
+Runs on whatever devices exist (the CPU in tests, TPU chips in training —
+the same code path: the mesh is just bigger).  The loop is plan → step →
+account: one PrivacyEngine owns the ExecPlan, the jitted private step, and the
 accountant; checkpointing, the straggler monitor, and chaos-monkey fault
 injection wrap around it.  ``--mesh data:8`` plans mesh-aware (per-layer
 collective-bytes costs, topology-keyed fingerprint — the plan table gains
@@ -26,6 +26,7 @@ surviving topology while the ledger and noise stream continue unbroken.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 
@@ -44,6 +45,7 @@ from repro.configs import get_config
 from repro.core import (ClipPolicy, DPConfig, PrivacyAccountant,
                         PrivacyEngine, costmodel)
 from repro.data import SyntheticImageDataset, SyntheticLMDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.optim import adamw_init, cosine_schedule
 from repro.runtime import ChaosMonkey, StepMonitor, WorkerFailure, \
@@ -77,7 +79,18 @@ def make_batch_fn(cfg, batch: int, seq: int):
     return fn
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainRun:
+    """What :func:`main` returns: the engine that ran, the per-step
+    losses, and the per-step wall-clock seconds (each measured to the
+    step's finished outputs; a segment's first step includes compiling)."""
+
+    engine: PrivacyEngine
+    losses: list
+    step_seconds: list
+
+
+def main(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -154,6 +167,7 @@ def main(argv=None):
                     help="override reduced d_model (e.g. ~100M scale)")
     ap.add_argument("--layers", type=int, default=0)
     args = ap.parse_args(argv)
+    print(f"[cache] compiled programs persist in {use_compile_cache()}")
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -245,12 +259,13 @@ def main(argv=None):
     if args.explain or dpc.strategy == "auto":
         print(engine.explain())
     if args.explain:
-        return []
+        return TrainRun(engine, [], [])
     if args.plan_json and not os.path.exists(args.plan_json):
         engine.save_plan(args.plan_json)
         print(f"[plan] wrote {args.plan_json}")
 
     mesh_axes_now = costmodel.mesh_axes(mesh)
+    step_seconds = []
 
     def train_state(params, opt):
         return DPTrainState(
@@ -306,7 +321,12 @@ def main(argv=None):
             batch = jax.tree.map(jnp.asarray, batch_fn(step))
             params, opt, loss, aux = engine.private_step(
                 params, opt, batch, step=step)
+            # The step returns once it is enqueued: time it to its
+            # finished outputs, or the re-plan loop and the straggler
+            # monitor read the dispatch cost.
+            jax.block_until_ready((params, opt, loss))
             dt = mon.stop(step)
+            step_seconds.append(dt)
             if skip_observe:
                 skip_observe = False
             else:
@@ -347,7 +367,7 @@ def main(argv=None):
           f"replans={len(mon.replans)}")
     if args.noise:
         print(engine.report())
-    return losses
+    return TrainRun(engine, losses, step_seconds)
 
 
 if __name__ == "__main__":

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from repro import calibrate
 from repro.core import DPConfig, PrivacyAccountant, PrivacyEngine, costmodel
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_auto_mesh
 from repro.optim import adamw_init
 from repro.runtime.monitor import StepMonitor
 
@@ -504,7 +505,7 @@ def test_sharded_replan_continues_training(toy_model):
     batch = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0),
                          toy_model[2])
     params0, batch_fn = toy_model[1], _batch_fn(batch)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     calib = calibrate.injected(mesh="data:8",
                                collective_bytes_per_second=1e9)
     mon = StepMonitor()

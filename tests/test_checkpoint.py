@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import Checkpointer, CheckpointCorrupt, DPTrainState
+from repro.launch.mesh import make_auto_mesh
 
 
 @pytest.fixture
@@ -60,7 +61,7 @@ def test_async_save(tmp_path, tree):
 def test_restore_with_shardings(tmp_path, tree):
     """Elastic path: restore places leaves onto given shardings."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_auto_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     ck = Checkpointer(str(tmp_path))
     ck.save(0, tree)

@@ -19,12 +19,13 @@ from repro.configs import get_config
 from repro.core import DPConfig
 from repro.core.clipping import dp_gradient
 from repro.launch import sharding as shd
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.dryrun import abstract_params, cache_sharding, \
     cost_analysis_dict, parse_collectives
 from repro.models.registry import build_model
 from repro.optim import adamw_init, adamw_update
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("llama3.2-1b").reduced().replace(dtype="bfloat16")
 model = build_model(cfg)
 

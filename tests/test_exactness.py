@@ -26,6 +26,7 @@ from repro.core import (ClipPolicy, clipped_grad_sum,
                         resolve_budgets)
 from repro.core.strategies import clip_coefficients
 from repro.core.tapper import Tapper
+from repro.launch.mesh import make_auto_mesh
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -652,7 +653,7 @@ def test_sharded_engine_passes_oracle(dtype):
     from repro.core import DPConfig, PrivacyEngine
 
     apply_fn, params, batch = conv_model(dtype, CONV_GEOMS[1], B=8, seed=7)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     C = 0.1
     engine = PrivacyEngine(apply_fn, params, batch, dp=DPConfig(l2_clip=C),
                            optimizer=_grad_extracting_optimizer, mesh=mesh)
@@ -683,7 +684,7 @@ def test_sharded_per_layer_passes_oracle(dtype):
 
     apply_fn, params, batch = conv_plus_head_model(dtype, CONV_GEOMS[1],
                                                    B=8, seed=7)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     C = 0.1
     engine = PrivacyEngine(
         apply_fn, params, batch,
@@ -717,7 +718,7 @@ def test_sharded_stale_passes_oracle(dtype):
 
     apply_fn, params, batch = conv_plus_head_model(dtype, CONV_GEOMS[1],
                                                    B=8, seed=7)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     C = 0.1
     engine = PrivacyEngine(
         apply_fn, params, batch,
@@ -762,7 +763,7 @@ def test_sharded_2d_engine_passes_oracle(dtype):
     from repro.core import DPConfig, PrivacyEngine
 
     apply_fn, params, batch = conv_model(dtype, CONV_GEOMS[1], B=8, seed=7)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     C = 0.1
     engine = PrivacyEngine(apply_fn, params, batch, dp=DPConfig(l2_clip=C),
                            optimizer=_grad_extracting_optimizer, mesh=mesh,
@@ -799,7 +800,7 @@ def test_sharded_2d_per_layer_passes_oracle(dtype):
 
     apply_fn, params, batch = conv_plus_head_model(dtype, CONV_GEOMS[1],
                                                    B=8, seed=7)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     C = 0.1
     engine = PrivacyEngine(
         apply_fn, params, batch,
@@ -835,7 +836,7 @@ def test_sharded_2d_stale_passes_oracle(dtype):
 
     apply_fn, params, batch = conv_plus_head_model(dtype, CONV_GEOMS[1],
                                                    B=8, seed=7)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     C = 0.1
     engine = PrivacyEngine(
         apply_fn, params, batch,
@@ -962,7 +963,7 @@ def test_sharded_attn_engine_passes_oracle(dtype):
     from repro.core import DPConfig, PrivacyEngine, costmodel
 
     apply_fn, params, batch = gqa_attn_plus_head_model(dtype, B=8)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     C = 0.1
     costmodel.clear_plan_cache()
     engine = PrivacyEngine(apply_fn, params, batch, dp=DPConfig(l2_clip=C),

@@ -8,8 +8,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attn import flash_attention
-from repro.kernels.gram_norm import (gram_norm, gram_norm_fused,
-                                     gram_norm_tokmask)
+from repro.kernels.gram_norm import gram_norm, gram_norm_fused
 from repro.kernels.pe_conv_grad import pe_conv_grad_1d, pe_conv_grad_2d
 
 
@@ -50,16 +49,6 @@ def test_gram_norm_fused_kernel_vs_ref(shape, dtype, has_bias):
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(cb_k), np.asarray(cb_r),
                                rtol=rtol, atol=1e-5)
-
-
-@pytest.mark.parametrize("bt", [8, 16, 64])
-def test_gram_norm_tokmask(bt):
-    rng = np.random.RandomState(bt)
-    ids = jnp.array(rng.randint(0, 7, (2, 33)))
-    dy = jnp.array(rng.randn(2, 33, 9), jnp.float32)
-    got = gram_norm_tokmask(ids, dy, bt=bt, interpret=True)
-    want = ref.gram_norm_tokmask_ref(ids, dy)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4)
 
 
 @pytest.mark.parametrize("shape", [(2, 5, 6, 20, 3), (1, 3, 8, 33, 5),

@@ -235,19 +235,23 @@ def test_dense_norm_and_contrib_methods():
 
 
 def test_pe_conv_bd_autotune():
+    from repro.kernels import pe_conv_grad as pc
     bd = kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3))
-    assert 64 % bd == 0
-    # working set must fit the budget
-    assert 4 * (16 * 32 * 32 + bd * (30 * 30 + 16 * 9)) <= kops.VMEM_BUDGET
+    assert 64 % bd == 0 and (bd == 64 or bd % 8 == 0)
+    # padded working set must fit the budget
+    th = pc.row_tile(bd, 16, 30, 32, 3, 3, kops.VMEM_BUDGET)
+    assert pc.vmem_bytes(bd, 16, 32, th, 3, 3) <= kops.VMEM_BUDGET
     # a tiny budget forces tiling below full D
-    small = kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3), budget=1 << 18)
-    assert small < 64 and 64 % small == 0
-    # env override wins, rounded down to a divisor of D
+    small = kops.pick_bd(512, 16, (32, 32), (30, 30), (3, 3),
+                         budget=1 << 20)
+    assert small < 512 and 512 % small == 0 and small % 8 == 0
+    # env override wins, rounded down to a legal tile (a multiple of 8
+    # dividing D, or D)
     try:
-        os.environ["REPRO_PE_CONV_BD"] = "8"
-        assert kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3)) == 8
-        os.environ["REPRO_PE_CONV_BD"] = "7"  # not a divisor -> 4
-        assert kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3)) == 4
+        os.environ["REPRO_PE_CONV_BD"] = "16"
+        assert kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3)) == 16
+        os.environ["REPRO_PE_CONV_BD"] = "31"  # not legal -> 16
+        assert kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3)) == 16
     finally:
         del os.environ["REPRO_PE_CONV_BD"]
 

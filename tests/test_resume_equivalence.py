@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.checkpoint import Checkpointer, CheckpointCorrupt, DPTrainState
 from repro.core import (ClipPolicy, DPConfig, PrivacyAccountant,
                         PrivacyEngine, costmodel)
+from repro.launch.mesh import make_auto_mesh
 from repro.optim import adamw_init
 from repro.runtime import (ChaosMonkey, WorkerFailure, elastic_mesh_axes,
                            run_with_restarts)
@@ -247,7 +248,7 @@ def test_kill_and_resume_bit_identical_sharded(toy_model, tmp_path,
                                                kill_at):
     batch = _batch8(toy_model[2])
     params0, batch_fn = toy_model[1], _batch_fn(batch)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     ref_p, ref_o = _drive(_engine(toy_model, mesh=mesh, batch=batch),
                           params0, batch_fn)
     ck = Checkpointer(str(tmp_path))
@@ -271,7 +272,7 @@ def test_elastic_resume_replans_onto_smaller_mesh(toy_model, tmp_path):
     reduction order (bitwise is only guaranteed mesh-to-same-mesh)."""
     batch = _batch8(toy_model[2])
     params0, batch_fn = toy_model[1], _batch_fn(batch)
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_auto_mesh((8,), ("data",))
     ref_engine = _engine(toy_model, mesh=mesh8, batch=batch)
     ref_p, _ = _drive(ref_engine, params0, batch_fn)
     ck = Checkpointer(str(tmp_path))
@@ -282,7 +283,7 @@ def test_elastic_resume_replans_onto_smaller_mesh(toy_model, tmp_path):
     surv = elastic_mesh_axes((("data", 8),), 4, jax.tree.leaves(batch)[0]
                              .shape[0])
     assert surv == (("data", 4),)
-    mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    mesh4 = make_auto_mesh((4,), ("data",), devices=jax.devices()[:4])
     res_engine = _engine(toy_model, mesh=mesh4, batch=batch)
     st, _ = ck.restore_state(params0, adamw_init(params0))
     # the elastic cross-check: mismatch vanishes when re-keyed under the

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from conftest import tree_maxdiff
 from repro.core import DPConfig, ExecPlan, PrivacyEngine, costmodel
+from repro.launch.mesh import make_auto_mesh
 from repro.optim import adamw_init
 
 needs_8_devices = pytest.mark.skipif(
@@ -218,11 +219,11 @@ def test_batch_sharding_requires_a_data_axis():
     a model-parallel-only mesh is rejected up front, not with an obscure
     IndexError inside jit setup."""
     from repro.launch.sharding import batch_sharding
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_auto_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="no data-parallel axis"):
         batch_sharding({"x": jnp.zeros((4, 2))}, mesh)
     # a 'batch'-named axis counts as data parallelism, like the planner
-    mesh_b = jax.make_mesh((1,), ("batch",))
+    mesh_b = make_auto_mesh((1,), ("batch",))
     sh = batch_sharding({"x": jnp.zeros((4, 2))}, mesh_b)
     assert jax.tree.leaves(sh)[0].spec == jax.sharding.PartitionSpec("batch")
 
@@ -236,7 +237,7 @@ def test_batch_sharding_requires_a_data_axis():
 def test_sharded_private_step_matches_single_device(toy_model):
     apply_fn, params, batch4 = toy_model
     batch = _batch8(batch4)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     dp = DPConfig(l2_clip=0.1)
     e1 = PrivacyEngine(apply_fn, params, batch, dp=dp, lr=1e-2)
     e8 = PrivacyEngine(apply_fn, params, batch, dp=dp, lr=1e-2, mesh=mesh)
@@ -257,7 +258,7 @@ def test_sharded_noise_is_replicated_not_per_shard(toy_model):
     single-device noisy step bit-for-bit up to reduction order."""
     apply_fn, params, batch4 = toy_model
     batch = _batch8(batch4)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     dp = DPConfig(l2_clip=0.1, noise_multiplier=1.3)
     key = jax.random.key_data(jax.random.PRNGKey(7))
     e1 = PrivacyEngine(apply_fn, params, batch, dp=dp, lr=1e-2)
@@ -273,7 +274,7 @@ def test_engine_rejects_indivisible_batch_up_front(toy_model):
     """A live mesh whose data degree does not divide the batch fails at
     engine construction with a named error, not inside XLA."""
     apply_fn, params, batch4 = toy_model   # B=4 on an 8-way data mesh
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     with pytest.raises(ValueError, match="not divisible.*degree 8"):
         PrivacyEngine(apply_fn, params, batch4,
                       dp=DPConfig(l2_clip=0.1), mesh=mesh)
@@ -284,7 +285,7 @@ def test_engine_rejects_indivisible_batch_up_front(toy_model):
 def test_sharded_step_places_batch_on_data_axis(toy_model):
     apply_fn, params, batch4 = toy_model
     batch = _batch8(batch4)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     engine = PrivacyEngine(apply_fn, params, batch,
                            dp=DPConfig(l2_clip=0.1), mesh=mesh)
     p, _, _, _ = engine.private_step(params, adamw_init(params), batch)
@@ -303,7 +304,7 @@ def test_live_mesh_and_spec_plan_identically(toy_model):
     host and vice versa."""
     apply_fn, params, batch4 = toy_model
     batch = _batch8(batch4)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_auto_mesh((8,), ("data",))
     fp_live = costmodel.plan_fingerprint(apply_fn, params, batch, mesh=mesh)
     fp_spec = costmodel.plan_fingerprint(apply_fn, params, batch,
                                          mesh="data:8")
@@ -461,7 +462,7 @@ def test_2d_engine_auto_calibrates_by_default(toy_model, monkeypatch):
 
     apply_fn, params, batch4 = toy_model
     batch = _batch8(batch4)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     calls = []
     fake = calibrate.injected(
         mesh="data:4,model:2",
@@ -512,7 +513,7 @@ def test_sharded_2d_private_step_matches_single_device(arch):
     e1 = PrivacyEngine(model.apply, params, batch_fn(0), dp=dp,
                        optimizer="sgdm", lr=1e-2, run_seed=7,
                        sampling_rate=0.01, calibration="analytic")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     costmodel.clear_plan_cache()
     e2 = PrivacyEngine(model.apply, params, batch_fn(0), dp=dp,
                        optimizer="sgdm", lr=1e-2, mesh=mesh,
@@ -571,7 +572,7 @@ def test_sharded_2d_custom_optimizer_state_inherits_param_layout():
     e1 = PrivacyEngine(model.apply, params, batch_fn(0), dp=dp,
                        optimizer=momentum, lr=1e-2, run_seed=7,
                        calibration="analytic")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     costmodel.clear_plan_cache()
     e2 = PrivacyEngine(model.apply, params, batch_fn(0), dp=dp,
                        optimizer=momentum, lr=1e-2, mesh=mesh,

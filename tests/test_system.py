@@ -15,7 +15,7 @@ def test_dp_training_decreases_loss(tmp_path):
     losses = train_mod.main([
         "--arch", "llama3.2-1b", "--steps", "40", "--batch", "16",
         "--seq", "64", "--lr", "1e-2", "--clip", "1.0", "--noise", "0.1",
-        "--strategy", "ghost"])
+        "--strategy", "ghost"]).losses
     assert np.mean(losses[-5:]) < np.mean(losses[:5]) - 0.05
 
 
@@ -25,9 +25,9 @@ def test_restart_reproduces_run(tmp_path):
     with the same loss as an uninterrupted run (determinism contract)."""
     common = ["--arch", "llama3.2-1b", "--steps", "24", "--batch", "4",
               "--seq", "32", "--strategy", "bk", "--ckpt-every", "8"]
-    a = train_mod.main(common + ["--ckpt-dir", str(tmp_path / "a")])
+    a = train_mod.main(common + ["--ckpt-dir", str(tmp_path / "a")]).losses
     b = train_mod.main(common + ["--ckpt-dir", str(tmp_path / "b"),
-                                 "--fail-at", "15"])
+                                 "--fail-at", "15"]).losses
     assert abs(a[-1] - b[-1]) < 1e-4
 
 
@@ -35,7 +35,7 @@ def test_restart_reproduces_run(tmp_path):
 def test_cnn_dp_training(tmp_path):
     losses = train_mod.main([
         "--arch", "alexnet", "--steps", "25", "--batch", "8",
-        "--lr", "2e-3", "--strategy", "crb"])
+        "--lr", "2e-3", "--strategy", "crb"]).losses
     assert np.mean(losses[-5:]) < np.mean(losses[:5])
 
 

@@ -1,0 +1,131 @@
+"""Every Pallas kernel compiled ahead of time for a TPU v5e chip, at the
+widths the main path feeds it.  Interpret mode (tests/test_kernels.py)
+checks the kernels' arithmetic; only the TPU compiler checks their block
+shapes, layouts and VMEM use, and it runs here without a chip: the chip is
+described (``v5e:2x2``), not attached."""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import flash_attn, gram_norm, ops
+from repro.kernels import pe_conv_grad as pc
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described v5e chip, with the persistent compilation cache off
+    while this module compiles for it (a TPU executable written to the
+    cache cannot be read back on the CPU)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# VGG16 at 3x256x256, batch 16: the patch matrices its conv layers feed the
+# Gram kernels (T = out_h * out_w, Di = C * 9, Do = D) and fc0.
+VGG_DENSE = {
+    "conv8": (16, 32 * 32, 512 * 9, 512),
+    "conv10": (16, 16 * 16, 512 * 9, 512),
+    "fc0": (16, 1, 512 * 8 * 8, 4096),
+}
+
+
+@pytest.mark.parametrize("layer", sorted(VGG_DENSE))
+@pytest.mark.parametrize("has_bias", [False, True])
+def test_gram_norm_fused_compiles(one_chip, layer, has_bias):
+    B, T, Di, Do = VGG_DENSE[layer]
+    _compile(lambda x, dy, w: gram_norm.gram_norm_fused(
+        x, dy, w, has_bias=has_bias, interpret=False),
+        [((B, T, Di), F32), ((B, T, Do), F32), ((B,), F32)], one_chip)
+
+
+@pytest.mark.parametrize("layer", sorted(VGG_DENSE))
+def test_gram_norm_compiles(one_chip, layer):
+    B, T, Di, Do = VGG_DENSE[layer]
+    _compile(lambda x, dy: gram_norm.gram_norm(x, dy, has_bias=True,
+                                               interpret=False),
+             [((B, T, Di), F32), ((B, T, Do), F32)], one_chip)
+
+
+# (C, D, input side after padding, output side) of VGG16 convs at 256 px.
+VGG_CONV = {"conv1": (64, 64, 258, 256), "conv4": (128, 256, 66, 64),
+            "conv8": (512, 512, 34, 32)}
+
+
+@pytest.mark.parametrize("layer", sorted(VGG_CONV))
+def test_pe_conv_grad_2d_compiles(one_chip, layer):
+    C, D, S, So = VGG_CONV[layer]
+    B = 16
+    bd = ops.pick_bd(D, C, (S, S), (So, So), (3, 3))
+    th = pc.row_tile(bd, C, So, S, 3, 3, ops.VMEM_BUDGET)
+    _compile(lambda x, dy: pc.pe_conv_grad_2d(x, dy, KH=3, KW=3, bd=bd,
+                                              th=th, interpret=False),
+             [((B, C, S, S), F32), ((B, D, So, So), F32)], one_chip)
+
+
+def test_pe_conv_grad_1d_compiles(one_chip):
+    B, C, D, T, K = 16, 256, 256, 1026, 3
+    bd = ops.pick_bd(D, C, (T,), (T - K + 1,), (K,))
+    th = pc.row_tile(bd, C, T - K + 1, 1, K, 1, ops.VMEM_BUDGET)
+    _compile(lambda x, dy: pc.pe_conv_grad_1d(x, dy, K=K, bd=bd, th=th,
+                                              interpret=False),
+             [((B, C, T), F32), ((B, D, T - K + 1), F32)], one_chip)
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_flash_attention_fwd_bwd_compiles(one_chip, precision):
+    """bf16, T = 4096, head_dim 128, 4 query heads per kv head: the
+    forward kernel and both backward kernels, also inside
+    ``jax.default_matmul_precision("highest")`` (Mosaic refuses an fp32
+    contract precision on bf16 operands)."""
+    B, T, H, Hkv, hd = 1, 4096, 8, 2, 128
+
+    def loss(q, k, v):
+        o = flash_attn.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(o.astype(F32))
+
+    with jax.default_matmul_precision(precision):
+        compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                            [((B, T, H, hd), BF16), ((B, T, Hkv, hd), BF16),
+                             ((B, T, Hkv, hd), BF16)], one_chip)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_bf16_kernels_compile_at_highest_precision(one_chip):
+    """The Gram and conv kernels on bf16 captures inside
+    ``jax.default_matmul_precision("highest")``."""
+    B, T, Di, Do = VGG_DENSE["conv10"]
+    C, D, S, So = VGG_CONV["conv8"]
+    with jax.default_matmul_precision("highest"):
+        _compile(lambda x, dy, w: gram_norm.gram_norm_fused(
+            x, dy, w, has_bias=True, interpret=False),
+            [((B, T, Di), BF16), ((B, T, Do), BF16), ((B,), F32)], one_chip)
+        _compile(lambda x, dy: gram_norm.gram_norm(x, dy, interpret=False),
+                 [((B, T, Di), BF16), ((B, T, Do), BF16)], one_chip)
+        _compile(lambda x, dy: pc.pe_conv_grad_2d(x, dy, KH=3, KW=3, bd=64,
+                                                  th=8, interpret=False),
+                 [((B, C, S, S), BF16), ((B, D, So, So), BF16)], one_chip)
