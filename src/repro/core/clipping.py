@@ -290,15 +290,16 @@ def add_noise(grad_sum, key, noise_multiplier: float, l2_clip: float):
         return grad_sum
     from repro.analysis.markers import tag
     leaves, treedef = jax.tree.flatten(grad_sum)
-    keys = jax.random.split(key, len(leaves))
     sigma = noise_multiplier * l2_clip
     noisy = []
-    for g, k in zip(leaves, keys):
-        noise = tag(sigma * jax.random.normal(k, g.shape, jnp.float32),
-                    kind="noise", sigma=float(sigma),
-                    noise_multiplier=float(noise_multiplier),
-                    l2_clip=float(l2_clip))
-        noisy.append((g.astype(jnp.float32) + noise).astype(g.dtype))
+    with jax.named_scope("dp.noise"):
+        keys = jax.random.split(key, len(leaves))
+        for g, k in zip(leaves, keys):
+            noise = tag(sigma * jax.random.normal(k, g.shape, jnp.float32),
+                        kind="noise", sigma=float(sigma),
+                        noise_multiplier=float(noise_multiplier),
+                        l2_clip=float(l2_clip))
+            noisy.append((g.astype(jnp.float32) + noise).astype(g.dtype))
     return jax.tree.unflatten(treedef, noisy)
 
 

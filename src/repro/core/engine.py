@@ -35,6 +35,18 @@ every device adds the *same* noise instead of per-shard draws: the
 sharded step equals the single-device step up to reduction order.  A
 mesh *spec* ("data:8", axes dict) is also accepted for planning-only
 use on hosts without the devices.
+
+Under ``jax.profiler`` each phase of the step is named in the trace.  On
+the device, the metadata of every operation carries its phase's
+``jax.named_scope``: ``dp.capture`` (forward and backward with taps),
+``dp.norm/<norm method>/<group>``, ``dp.clip``,
+``dp.contrib/<sum method>/<group>`` (``dp.contrib/backward`` for the
+shared weighted backward), ``dp.noise`` and ``dp.update``.  On the host,
+``private_step`` writes the spans ``engine.private_step`` and, inside it,
+``engine.noise_key``, ``engine.dispatch`` and ``engine.absorb_clip_aux``;
+``engine.trace`` appears only while ``jax.jit`` (re)traces the step.
+Without the profiler the scopes are metadata only and each span costs one
+check.
 """
 from __future__ import annotations
 
@@ -46,6 +58,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import costmodel
 from repro.core.clipping import (DPConfig, dp_gradient, resolve_budgets,
@@ -613,12 +626,16 @@ class PrivacyEngine:
         apply_fn = self.apply_fn
 
         def step(params, opt, batch, key, clip_state):
-            loss, grad, aux = dp_gradient(apply_fn, params, batch, cfg=cfg,
-                                          key=key, plan=plan,
-                                          clip_state=clip_state)
-            lr_t = lr(opt["step"]) if callable(lr) else lr
-            params, opt = update_fn(grad, opt, params, lr=lr_t,
-                                    weight_decay=wd)
+            # Runs only while jax.jit traces the step: a (re)trace shows in
+            # a profile as this span.
+            with TraceAnnotation("engine.trace"):
+                loss, grad, aux = dp_gradient(
+                    apply_fn, params, batch, cfg=cfg, key=key, plan=plan,
+                    clip_state=clip_state)
+                with jax.named_scope("dp.update"):
+                    lr_t = lr(opt["step"]) if callable(lr) else lr
+                    params, opt = update_fn(grad, opt, params, lr=lr_t,
+                                            weight_decay=wd)
             return params, opt, loss, aux
 
         return step
@@ -738,12 +755,17 @@ class PrivacyEngine:
         first step bootstraps with exact flat clipping); ``per_layer``
         with ``budgets="auto"`` re-splits the budget from the tracked
         per-layer norm quantiles after every step."""
-        self._record_opt_spec(opt)
-        out = self._jit_step(params, opt, batch, self._check_key(key, step),
-                             self._clip_state())
-        self._absorb_clip_aux(out[3])
-        if self.accountant is not None:
-            self.accountant.step()
+        with TraceAnnotation("engine.private_step"):
+            self._record_opt_spec(opt)
+            with TraceAnnotation("engine.noise_key"):
+                key = self._check_key(key, step)
+            clip_state = self._clip_state()
+            with TraceAnnotation("engine.dispatch"):
+                out = self._jit_step(params, opt, batch, key, clip_state)
+            with TraceAnnotation("engine.absorb_clip_aux"):
+                self._absorb_clip_aux(out[3])
+            if self.accountant is not None:
+                self.accountant.step()
         return out
 
     # -- accounting --------------------------------------------------------

@@ -146,6 +146,22 @@ def group_key_of(path: tuple) -> str:
     return "/".join(str(p) for p in path)
 
 
+def phase_scope(phase: str, method: str | None = None,
+                path: tuple | None = None):
+    """``jax.named_scope`` of one phase of the private step: ``dp.<phase>``
+    or, for one parameter group, ``dp.<phase>/<method>/<group>`` with the
+    group key's "/" made "." (one component).  The device operations the
+    phase compiles into carry the name in their metadata, and the profiler
+    trace keeps it; ``method`` and the group are the strings the phase's
+    ``dp_tag`` carries."""
+    name = f"dp.{phase}"
+    if method is not None:
+        name += f"/{method}"
+    if path is not None:
+        name += "/" + group_key_of(path).replace("/", ".")
+    return jax.named_scope(name)
+
+
 def group_norms_from_captures(params, caps, dtaps, metas, *,
                               norm_method: str = "auto",
                               conv_impl: str = "fgc",
@@ -168,23 +184,22 @@ def group_norms_from_captures(params, caps, dtaps, metas, *,
     B = _batch_size(metas, dtaps)
     keys, norms = [], []
 
-    def _tagged(n_sq, path, method="unplanned"):
-        return tag(n_sq, kind="group_norm", group=group_key_of(path),
-                   method=method, fused=False)
-
-    for path, names in sorted(by_param.items()):
-        keys.append(group_key_of(path))
-        psub = get_subtree(params, path)
+    def _method(names):
         if len(names) == 1:
+            return "unplanned"
+        ks = sorted((metas[n].kind, metas[n].w_transposed) for n in names)
+        return "tied" if ks == [("dense", True), ("embed", False)] else "pe"
+
+    def _group_norm(path, names, method):
+        psub = get_subtree(params, path)
+        if method == "unplanned":
             n = names[0]
-            norms.append(_tagged(kinds.apply_kind(
+            return kinds.apply_kind(
                 "norm_sq", metas[n], caps[n], dtaps[n], params_sub=psub,
                 norm_method=norm_method, conv_impl=conv_impl,
                 embed_method=embed_method, conv_norm=conv_norm,
-                attn_norm=attn_norm), path))
-            continue
-        ks = sorted((metas[n].kind, metas[n].w_transposed) for n in names)
-        if ks == [("dense", True), ("embed", False)] and len(names) == 2:
+                attn_norm=attn_norm)
+        if method == "tied":
             # Tied embedding + LM head: per-tap norms plus the cross term.
             n_e = next(n for n in names if metas[n].kind == "embed")
             n_d = next(n for n in names if metas[n].kind == "dense")
@@ -194,9 +209,8 @@ def group_norms_from_captures(params, caps, dtaps, metas, *,
             n_g = n_g + kinds.apply_kind(
                 "norm_sq", metas[n_d], caps[n_d], dtaps[n_d], params_sub=psub,
                 norm_method=norm_method)
-            norms.append(_tagged(n_g + kinds.tied_embed_head_cross(
-                caps[n_e], dtaps[n_e], caps[n_d], dtaps[n_d]), path, "tied"))
-            continue
+            return n_g + kinds.tied_embed_head_cross(
+                caps[n_e], dtaps[n_e], caps[n_d], dtaps[n_d])
         # Generic exact fallback: materialize the summed per-example grad.
         pe_sum: dict = {}
         for n in names:
@@ -204,7 +218,15 @@ def group_norms_from_captures(params, caps, dtaps, metas, *,
                                   params_sub=psub, conv_impl=conv_impl)
             for k, v in pe.items():
                 pe_sum[k] = pe_sum[k] + v if k in pe_sum else v
-        norms.append(_tagged(kinds._sumsq(pe_sum), path, "pe"))
+        return kinds._sumsq(pe_sum)
+
+    for path, names in sorted(by_param.items()):
+        keys.append(group_key_of(path))
+        method = _method(names)
+        with phase_scope("norm", method, path):
+            norms.append(tag(_group_norm(path, names, method),
+                             kind="group_norm", group=group_key_of(path),
+                             method=method, fused=False))
     if not norms:
         raise ValueError("no tapped layers")
     return tuple(keys), jnp.stack(norms)
@@ -229,8 +251,9 @@ def ghost_norms(apply_fn, params, batch, **kw):
 
 def clip_coefficients(norms_sq, l2_clip, eps: float = 1e-12, *,
                       mode: str = "flat"):
-    norms = jnp.sqrt(norms_sq + eps)
-    coef = jnp.minimum(1.0, l2_clip / norms)
+    with phase_scope("clip"):
+        norms = jnp.sqrt(norms_sq + eps)
+        coef = jnp.minimum(1.0, l2_clip / norms)
     # Structural marker the static verifier keys on: downstream of this
     # tag, multiplying by ``coef`` IS the clip contraction.  A mutant
     # that replaces the coefficients wholesale loses the tag — itself a
@@ -246,9 +269,10 @@ def clip_coefficients(norms_sq, l2_clip, eps: float = 1e-12, *,
 
 def per_layer_clip_coefficients(group_norms_sq, budgets, eps: float = 1e-12):
     """(G, B) coefficients: each group clipped against its own budget."""
-    norms = jnp.sqrt(group_norms_sq + eps)
-    return tag(jnp.minimum(1.0, budgets[:, None] / norms),
-               kind="clip_coef", mode="per_layer")
+    with phase_scope("clip"):
+        norms = jnp.sqrt(group_norms_sq + eps)
+        coef = jnp.minimum(1.0, budgets[:, None] / norms)
+    return tag(coef, kind="clip_coef", mode="per_layer")
 
 
 def _pe_tree_norms_sq(pe_grads):
@@ -378,17 +402,19 @@ def clipped_grad_sum_detailed(apply_fn, params, batch, *, l2_clip: float,
 
         STATS.forwards += 1
         STATS.backwards += 1
-        gsum = jax.grad(wloss)(params)
+        with phase_scope("contrib", "backward"):
+            gsum = jax.grad(wloss)(params)
         return losses, gsum, norms_sq, detail
 
     if strategy == "bk":
         acc: dict = {}
         for name, meta in metas.items():
-            contrib = kinds.apply_kind(
-                "contrib", meta, caps[name], dtaps[name],
-                params_sub=get_subtree(params, meta.path),
-                weights=weight_of(meta), conv_impl=conv_impl)
-            _accumulate_param_grads(acc, meta.path, contrib)
+            with phase_scope("contrib", "contrib", meta.path):
+                contrib = kinds.apply_kind(
+                    "contrib", meta, caps[name], dtaps[name],
+                    params_sub=get_subtree(params, meta.path),
+                    weights=weight_of(meta), conv_impl=conv_impl)
+                _accumulate_param_grads(acc, meta.path, contrib)
         gsum = _grads_to_tree(acc)
         if check:
             missing = check_coverage(params, gsum)
@@ -432,10 +458,26 @@ def _group_norm_tag(n_sq, g, method: str, fused: bool = False):
                method=method, fused=fused)
 
 
+def _norm_method_of(g, plan) -> str:
+    """The realization of one plan group's norm, as its ``dp.norm`` scope
+    names it: the planned norm method of a single layer (also where its
+    per-example grads are stashed), ``tied`` or ``pe``."""
+    if g.norm_mode == "single":
+        return plan.layers[g.members[0]].norm_method
+    return "tied" if g.norm_mode == "tied" else "pe"
+
+
 def _planned_group_norm(g, plan, metas, caps, dtaps, params, conv_impl,
                         stash):
     """Phase-1 norm of one plan group: (B,) squared norms, stashing any
     per-example grads the chosen realization materialized."""
+    with phase_scope("norm", _norm_method_of(g, plan), g.path):
+        return _realize_group_norm(g, plan, metas, caps, dtaps, params,
+                                   conv_impl, stash)
+
+
+def _realize_group_norm(g, plan, metas, caps, dtaps, params, conv_impl,
+                        stash):
     psub = get_subtree(params, g.path)
     if g.norm_mode == "single":
         n = g.members[0]
@@ -484,45 +526,59 @@ def _stale_group_norm_contrib(g, plan, metas, caps, dtaps, params, coef,
     from the same captures, with the fused ``gram_norm_fused``
     realization where the plan selected it."""
     psub = get_subtree(params, g.path)
+    norm_scope = phase_scope("norm", _norm_method_of(g, plan), g.path)
     if g.norm_mode == "single":
         n = g.members[0]
         lp, meta = plan.layers[n], metas[n]
         if lp.fused and fused_ok:
-            n_g, contrib = kinds.apply_norm_contrib(
-                meta, caps[n], dtaps[n], weights=coef, params_sub=psub,
-                fused=True, conv_impl=conv_impl, **_norm_kwargs(lp))
-            _accumulate_param_grads(acc, g.path, contrib)
+            # One pass gives both: the norm's scope holds it.
+            with norm_scope:
+                n_g, contrib = kinds.apply_norm_contrib(
+                    meta, caps[n], dtaps[n], weights=coef, params_sub=psub,
+                    fused=True, conv_impl=conv_impl, **_norm_kwargs(lp))
+                _accumulate_param_grads(acc, g.path, contrib)
             return _group_norm_tag(n_g, g, lp.norm_method, fused=True)
         if lp.stash:
-            pe = kinds.apply_kind("pe_grad", meta, caps[n], dtaps[n],
-                                  params_sub=psub, conv_impl=conv_impl)
-            _accumulate_param_grads(acc, g.path, _weighted_stash_sum(pe, coef))
-            return _group_norm_tag(kinds._sumsq(pe), g, "stash")
-        n_g = kinds.apply_kind(
-            "norm_sq", meta, caps[n], dtaps[n], params_sub=psub,
-            conv_impl=conv_impl, **_norm_kwargs(lp))
-        _accumulate_param_grads(acc, g.path, kinds.apply_kind(
-            "contrib", meta, caps[n], dtaps[n], params_sub=psub,
-            weights=coef, conv_impl=conv_impl))
+            with norm_scope:
+                pe = kinds.apply_kind("pe_grad", meta, caps[n], dtaps[n],
+                                      params_sub=psub, conv_impl=conv_impl)
+                n_g = kinds._sumsq(pe)
+            with phase_scope("contrib", "stash", g.path):
+                _accumulate_param_grads(acc, g.path,
+                                        _weighted_stash_sum(pe, coef))
+            return _group_norm_tag(n_g, g, "stash")
+        with norm_scope:
+            n_g = kinds.apply_kind(
+                "norm_sq", meta, caps[n], dtaps[n], params_sub=psub,
+                conv_impl=conv_impl, **_norm_kwargs(lp))
+        with phase_scope("contrib", "contrib", g.path):
+            _accumulate_param_grads(acc, g.path, kinds.apply_kind(
+                "contrib", meta, caps[n], dtaps[n], params_sub=psub,
+                weights=coef, conv_impl=conv_impl))
         return _group_norm_tag(n_g, g, lp.norm_method)
     if g.norm_mode == "tied":
         stash: dict = {}
         n_g = _planned_group_norm(g, plan, metas, caps, dtaps, params,
                                   conv_impl, stash)
-        for n in g.members:
-            _accumulate_param_grads(acc, g.path, kinds.apply_kind(
-                "contrib", metas[n], caps[n], dtaps[n], params_sub=psub,
-                weights=coef, conv_impl=conv_impl))
+        with phase_scope("contrib", "contrib", g.path):
+            for n in g.members:
+                _accumulate_param_grads(acc, g.path, kinds.apply_kind(
+                    "contrib", metas[n], caps[n], dtaps[n], params_sub=psub,
+                    weights=coef, conv_impl=conv_impl))
         return n_g
     # group_pe: the materialized summed per-example grad serves both.
-    pe_sum: dict = {}
-    for n in g.members:
-        pe = kinds.apply_kind("pe_grad", metas[n], caps[n], dtaps[n],
-                              params_sub=psub, conv_impl=conv_impl)
-        for k, v in pe.items():
-            pe_sum[k] = pe_sum[k] + v if k in pe_sum else v
-    _accumulate_param_grads(acc, g.path, _weighted_stash_sum(pe_sum, coef))
-    return _group_norm_tag(kinds._sumsq(pe_sum), g, "pe")
+    with norm_scope:
+        pe_sum: dict = {}
+        for n in g.members:
+            pe = kinds.apply_kind("pe_grad", metas[n], caps[n], dtaps[n],
+                                  params_sub=psub, conv_impl=conv_impl)
+            for k, v in pe.items():
+                pe_sum[k] = pe_sum[k] + v if k in pe_sum else v
+        n_g = kinds._sumsq(pe_sum)
+    with phase_scope("contrib", "stash", g.path):
+        _accumulate_param_grads(acc, g.path,
+                                _weighted_stash_sum(pe_sum, coef))
+    return _group_norm_tag(n_g, g, "pe")
 
 
 def planned_clipped_sum(apply_fn, params, batch, plan, *, l2_clip: float,
@@ -620,7 +676,8 @@ def planned_clipped_sum(apply_fn, params, batch, plan, *, l2_clip: float,
 
         STATS.forwards += 1
         STATS.backwards += 1
-        wgrads = jax.grad(wloss)(params)
+        with phase_scope("contrib", "backward"):
+            wgrads = jax.grad(wloss)(params)
 
     acc: dict = {}
     for gi, g in enumerate(plan.groups):
@@ -628,16 +685,19 @@ def planned_clipped_sum(apply_fn, params, batch, plan, *, l2_clip: float,
         if g.sum_method == "backward":
             _accumulate_param_grads(acc, g.path, get_subtree(wgrads, g.path))
             continue
-        if g.sum_method == "stash":
-            pe = stash[g.members[0] if g.norm_mode == "single" else g.path]
-            _accumulate_param_grads(acc, g.path, _weighted_stash_sum(pe, w))
-            continue
-        psub = get_subtree(params, g.path)
-        for n in g.members:
-            contrib = kinds.apply_kind(
-                "contrib", metas[n], caps[n], dtaps[n], params_sub=psub,
-                weights=w, conv_impl=conv_impl)
-            _accumulate_param_grads(acc, g.path, contrib)
+        with phase_scope("contrib", g.sum_method, g.path):
+            if g.sum_method == "stash":
+                pe = stash[g.members[0] if g.norm_mode == "single"
+                           else g.path]
+                _accumulate_param_grads(acc, g.path,
+                                        _weighted_stash_sum(pe, w))
+                continue
+            psub = get_subtree(params, g.path)
+            for n in g.members:
+                contrib = kinds.apply_kind(
+                    "contrib", metas[n], caps[n], dtaps[n], params_sub=psub,
+                    weights=w, conv_impl=conv_impl)
+                _accumulate_param_grads(acc, g.path, contrib)
 
     gsum = _grads_to_tree(acc)
     if check:

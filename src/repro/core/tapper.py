@@ -325,8 +325,9 @@ def capture_backward(apply_fn, params, batch, taps, *,
         losses = apply_fn(params, batch, tp)
         return jnp.sum(losses), (losses, tp.captures)
 
-    (_, (losses, caps)), dtaps = jax.value_and_grad(
-        loss_from_taps, has_aux=True)(taps)
+    with jax.named_scope("dp.capture"):
+        (_, (losses, caps)), dtaps = jax.value_and_grad(
+            loss_from_taps, has_aux=True)(taps)
     if with_metas:
         return losses, caps, dtaps, metas
     return losses, caps, dtaps

@@ -166,6 +166,7 @@ def _fwd_call(q, k, v, causal, bq, bk, interpret):
                    jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32)],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return jnp.moveaxis(out, 1, 2), lse
 
@@ -203,6 +204,7 @@ def _bwd_call(q, k, v, o, lse, do, causal, bq, bk, interpret):
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
         scratch_shapes=dq_scr,
         interpret=interpret,
+        name="flash_dq",
     )(qt, kt, vt, dot, lse, delta)
 
     # dk/dv: sequential last dim walks (head-group r, query block i) pairs
@@ -233,6 +235,7 @@ def _bwd_call(q, k, v, o, lse, do, causal, bq, bk, interpret):
                    jax.ShapeDtypeStruct((B, Hkv, S, hd), v.dtype)],
         scratch_shapes=dkv_scr,
         interpret=interpret,
+        name="flash_dkv",
     )(qt, kt, vt, dot, lse, delta)
     return (jnp.moveaxis(dq, 1, 2), jnp.moveaxis(dk, 1, 2),
             jnp.moveaxis(dv, 1, 2))

@@ -102,6 +102,7 @@ def gram_norm(x, dy, *, has_bias: bool = False, bt: int = DEFAULT_BT,
         out_specs=pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.float32),
         interpret=interpret,
+        name="gram_norm",
     )(x, x, dy, dy)
     return out[:, 0, 0]
 
@@ -195,6 +196,7 @@ def gram_norm_fused(x, dy, w, *, has_bias: bool = False,
         scratch_shapes=[pltpu.VMEM((tdi, tdo), jnp.float32),
                         pltpu.VMEM((1, tdo), jnp.float32)],
         interpret=interpret,
+        name="gram_norm_fused",
     )(w.astype(jnp.float32), x, dy)
     return (jnp.sum(norms, axis=(0, 1))[:, 0, 0], contrib[:Di, :Do],
             cb[0, 0, :Do])

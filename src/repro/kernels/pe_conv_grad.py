@@ -108,6 +108,7 @@ def pe_conv_grad_2d(x, dy, *, KH: int, KW: int, bd: int = 0, th: int = 0,
                                lambda b, d, r: (b, 0, d, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KH * KW, D, C), jnp.float32),
         interpret=interpret,
+        name="pe_conv_grad",
     )(xw, g)
     return out.reshape(B, KH, KW, D, C).transpose(0, 3, 4, 1, 2)
 
