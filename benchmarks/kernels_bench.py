@@ -87,7 +87,7 @@ def run():
     emit(f"kernels/gram_norm_fused/B{B}T{T}", t_fused,
          "interpret_mode_on_cpu")
 
-    # --- per-example conv grad: fgc vs bgc lowering + autotuned bd tile
+    # --- per-example conv grad: fgc vs bgc lowering + the kernel's row tile
     for (B, C, D, HW, K) in [(8, 16, 32, 32, 3), (4, 32, 64, 16, 5)]:
         x = jnp.array(rng.randn(B, C, HW, HW), jnp.float32)
         out_sp = HW - K + 1
@@ -97,9 +97,9 @@ def run():
                 a, b, kernel_spatial=(K, K), impl=i))
             t = time_fn(f, x, dy)
             emit(f"kernels/pe_conv/{impl}/B{B}C{C}D{D}", t, "")
-        bd = kops.pick_bd(D, C, (HW, HW), (out_sp, out_sp), (K, K))
-        emit(f"kernels/pe_conv/pallas_bd/B{B}C{C}D{D}", 0.0,
-             f"autotuned_bd={bd}_of_D{D}")
+        th = kops._pc.row_tile(HW, K, K, HW, kops.vmem_budget())
+        emit(f"kernels/pe_conv/pallas_th/B{B}C{C}D{D}", 0.0,
+             f"row_tile={th}_of_{HW}")
 
 
 def calibrate_only(calibration_out: str = "results/calibration.json",
@@ -123,7 +123,7 @@ def calibrate_only(calibration_out: str = "results/calibration.json",
     pe = calib.kernels.get("pe_conv_grad", {})
     if pe:
         emit("kernels/calibration/pe_conv_vmem_budget", 0.0,
-             f"winner={pe['vmem_budget']}_bd={pe['bd']}")
+             f"winner={pe['vmem_budget']}_th={pe['th']}")
 
     results = {}
     if os.path.exists(bench_out):

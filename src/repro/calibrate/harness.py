@@ -37,9 +37,10 @@ from repro.calibrate.table import (Calibration, CalibrationMeshMismatch,
 # streaming regime plans actually buy.
 COLLECTIVE_SIZES = (1 << 20, 8 << 20)
 COLLECTIVE_SIZES_QUICK = (1 << 20,)
-# pe_conv_grad VMEM budgets swept (bytes); VMEM_BUDGET's default 8 MiB
-# sits in the middle so the sweep can move it either way.
-VMEM_SWEEP = (1 << 20, 2 << 20, 4 << 20, 8 << 20, 16 << 20)
+# pe_conv_grad VMEM budgets swept (bytes); VMEM_BUDGET's default 48 MiB
+# sits in the middle so the sweep can move it either way, and the largest
+# stays below the kernel's scoped limit.
+VMEM_SWEEP = (12 << 20, 24 << 20, 48 << 20, 72 << 20)
 
 
 def _time(f, *args, iters: int = 3, warmup: int = 1) -> float:
@@ -103,10 +104,9 @@ def measure_collective_bytes_per_second(axis: str, size: int, *,
 
 def sweep_pe_conv_vmem(*, quick: bool = False,
                        budgets=VMEM_SWEEP) -> dict:
-    """The pending ``VMEM_BUDGET`` sweep: time ``pe_conv_grad`` under
-    each candidate budget's autotuned output-channel and row tiles and
-    report the winner.  Budgets that resolve to the same tiles share one
-    timing."""
+    """The ``VMEM_BUDGET`` sweep: time ``pe_conv_grad`` under each
+    candidate budget's row tile and report the winner.  Budgets that
+    resolve to the same tile share one timing."""
     from repro.kernels import ops as kops
 
     B, C, D, HW, K = (2, 8, 16, 12, 3) if quick else (4, 16, 32, 16, 3)
@@ -114,22 +114,17 @@ def sweep_pe_conv_vmem(*, quick: bool = False,
     x = jnp.asarray(rng.randn(B, C, HW, HW), jnp.float32)
     out_sp = HW - K + 1
     dy = jnp.asarray(rng.randn(B, D, out_sp, out_sp), jnp.float32)
-    by_tiles: dict[tuple, float] = {}
+    by_tile: dict[int, float] = {}
     sweep: dict[str, dict] = {}
     for budget in budgets:
-        bd = kops._autotune_bd(D, C, (HW, HW), (out_sp, out_sp), (K, K),
-                               budget)
-        th = kops._pc.row_tile(bd, C, out_sp, HW, K, K, budget)
-        if (bd, th) not in by_tiles:
-            f = jax.jit(lambda a, b, _bd=bd, _th=th:
-                        kops._pc.pe_conv_grad_2d(
-                            a, b, KH=K, KW=K, bd=_bd, th=_th,
-                            interpret=not kops.on_tpu()))
-            by_tiles[bd, th] = _time(f, x, dy, iters=2 if quick else 3)
-        sweep[str(budget)] = {"bd": bd, "th": th,
-                              "seconds": by_tiles[bd, th]}
+        th = kops._pc.row_tile(HW, K, K, HW, budget)
+        if th not in by_tile:
+            f = jax.jit(lambda a, b, _th=th: kops._pc.pe_conv_grad_2d(
+                a, b, KH=K, KW=K, th=_th, interpret=not kops.on_tpu()))
+            by_tile[th] = _time(f, x, dy, iters=2 if quick else 3)
+        sweep[str(budget)] = {"th": th, "seconds": by_tile[th]}
     winner = min(sweep, key=lambda k: sweep[k]["seconds"])
-    return {"vmem_budget": int(winner), "bd": sweep[winner]["bd"],
+    return {"vmem_budget": int(winner), "th": sweep[winner]["th"],
             "sweep": sweep}
 
 

@@ -161,7 +161,8 @@ class NormCfg:
     dense:     auto | gram | stream | rank1 | pallas
     embed:     auto | segsum | gram | pe
     conv:      auto | ghost | pe          (norm realization)
-    conv_impl: fgc | bgc | pallas         (materializing conv-grad impl)
+    conv_impl: auto | fgc | bgc | pallas  (materializing conv-grad impl;
+               auto: on a TPU, MXU matmuls for plain convs, else fgc)
     mem_budget: bytes of per-example-grad / capture scratch tolerated —
         bounds the planner's materializing paths AND drives
         ``microbatches="auto"``.
@@ -170,7 +171,7 @@ class NormCfg:
     dense: str = "auto"
     embed: str = "auto"
     conv: str = "auto"
-    conv_impl: str = "fgc"
+    conv_impl: str = "auto"
     mem_budget: int = costmodel.STREAM_MEM_BUDGET
 
 

@@ -365,7 +365,7 @@ def scale_contrib(meta: LayerMeta, cap, dy, w, gshape):
 # Convolution (the paper's contribution — Algorithms 1 & 2)
 
 
-def conv_pe_grad(meta: LayerMeta, cap, dy, impl: str = "fgc"):
+def conv_pe_grad(meta: LayerMeta, cap, dy, impl: str = "auto"):
     from repro.models import convops
     st = meta.static
     w_grad = convops.pe_conv_grad(
@@ -415,7 +415,7 @@ def conv_norm_sq_ghost(meta: LayerMeta, cap, dy, *, use_pallas: bool = False):
     return n
 
 
-def conv_norm_sq(meta: LayerMeta, cap, dy, impl: str = "fgc",
+def conv_norm_sq(meta: LayerMeta, cap, dy, impl: str = "auto",
                  method: str = "pe"):
     if method == "auto":
         st = meta.static
@@ -651,9 +651,9 @@ def _fold_into_seq(meta: LayerMeta, cap, dy):
 
 
 def apply_kind(op: str, meta: LayerMeta, cap, dy, *, params_sub=None,
-               weights=None, norm_method: str = "auto", conv_impl: str = "fgc",
-               embed_method: str = "segsum", conv_norm: str = "pe",
-               attn_norm: str = "auto"):
+               weights=None, norm_method: str = "auto",
+               conv_impl: str = "auto", embed_method: str = "segsum",
+               conv_norm: str = "pe", attn_norm: str = "auto"):
     """Dispatch `op` in {"pe_grad","norm_sq","contrib"} over any kind,
     handling stacked (scanned) axes and shared parameters."""
     kind = meta.kind
@@ -743,7 +743,7 @@ def apply_kind(op: str, meta: LayerMeta, cap, dy, *, params_sub=None,
 
 def apply_norm_contrib(meta: LayerMeta, cap, dy, *, weights,
                        params_sub=None, fused: bool = True,
-                       conv_impl: str = "fgc", norm_method: str = "auto",
+                       conv_impl: str = "auto", norm_method: str = "auto",
                        embed_method: str = "segsum",
                        conv_norm: str = "auto", attn_norm: str = "auto"):
     """Per-example squared norms *and* the weighted sum Σ_b w_b·g_b from

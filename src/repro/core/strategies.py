@@ -119,7 +119,7 @@ def check_coverage(params, grads_tree) -> list[str]:
     return sorted(p_paths - g_paths)
 
 
-def crb_per_example_grads(apply_fn, params, batch, *, conv_impl: str = "fgc",
+def crb_per_example_grads(apply_fn, params, batch, *, conv_impl: str = "auto",
                           check: bool = True):
     """The paper's method: 1 backward + per-layer reconstruction."""
     losses, caps, dtaps, metas = _capture(apply_fn, params, batch)
@@ -164,7 +164,7 @@ def phase_scope(phase: str, method: str | None = None,
 
 def group_norms_from_captures(params, caps, dtaps, metas, *,
                               norm_method: str = "auto",
-                              conv_impl: str = "fgc",
+                              conv_impl: str = "auto",
                               embed_method: str = "segsum",
                               conv_norm: str = "auto",
                               attn_norm: str = "auto"):
@@ -296,7 +296,7 @@ def clipped_grad_sum(apply_fn, params, batch, **kw):
 def clipped_grad_sum_detailed(apply_fn, params, batch, *, l2_clip: float,
                               strategy: str = "ghost",
                               norm_method: str = "auto",
-                              conv_impl: str = "fgc", check: bool = False,
+                              conv_impl: str = "auto", check: bool = False,
                               embed_method: str = "segsum",
                               conv_norm: str | None = None, overrides=None,
                               mem_budget: int | None = None, plan=None,
@@ -582,7 +582,7 @@ def _stale_group_norm_contrib(g, plan, metas, caps, dtaps, params, coef,
 
 
 def planned_clipped_sum(apply_fn, params, batch, plan, *, l2_clip: float,
-                        conv_impl: str = "fgc", check: bool = False,
+                        conv_impl: str = "auto", check: bool = False,
                         clip_policy=None, budgets=None, prev_norms_sq=None):
     """Execute a :class:`~repro.core.costmodel.ExecPlan`: one capture
     backward, per-layer planned norms (stashing any per-example grads the

@@ -37,6 +37,7 @@ model code serves ordinary training, serving, and every PEG strategy.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Callable
 
@@ -63,10 +64,12 @@ class PipelineStats:
 
     ``fused`` additionally counts fused norm+contrib realizations
     (``gram_norm_fused``-backed single passes picked by stale-coefficient
-    plans); it is not part of :meth:`snapshot`, which covers only the
-    whole-model pass counters."""
+    plans), and ``conv_impls`` the implementation each per-example conv
+    gradient took (``pallas`` for the MXU kernel, ``taps`` for per-tap
+    dots, ``fgc``, ``bgc``); they are not part of :meth:`snapshot`, which
+    covers only the whole-model pass counters."""
 
-    __slots__ = ("forwards", "backwards", "probes", "fused")
+    __slots__ = ("forwards", "backwards", "probes", "fused", "conv_impls")
 
     def __init__(self):
         self.reset()
@@ -76,6 +79,7 @@ class PipelineStats:
         self.backwards = 0
         self.probes = 0
         self.fused = 0
+        self.conv_impls = collections.Counter()
 
     def snapshot(self) -> dict:
         return {"forwards": self.forwards, "backwards": self.backwards,

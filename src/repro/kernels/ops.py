@@ -7,7 +7,6 @@ would be too slow.  ``on_tpu()`` centralizes the decision.
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -18,13 +17,17 @@ from repro.kernels import gram_norm as _gn
 from repro.kernels import pe_conv_grad as _pc
 from repro.kernels import ref as _ref
 
-# VMEM the pe_conv_grad autotuner plans one grid step against (padded
-# tiles, double-buffering included): half of the 16 MiB a v5e kernel may
-# scope by default.
+# VMEM the pe_conv_grad row tile is sized against (double-buffering
+# included), below the kernel's scoped limit ``pe_conv_grad.VMEM_LIMIT``.
 # The *analytic* default — vmem_budget() prefers the measured sweep
 # winner from a registered calibration, and REPRO_VMEM_BUDGET overrides
 # both.
-VMEM_BUDGET = 8 << 20
+VMEM_BUDGET = 48 << 20
+
+
+# Matmul precisions at which the TPU multiplies f32 operands in one bf16
+# MXU pass (jax_default_matmul_precision; None is the default).
+ONE_BF16_PASS = (None, "default", "fastest", "bfloat16")
 
 
 def on_tpu() -> bool:
@@ -74,84 +77,46 @@ def gram_norm_fused(x, dy, w, *, has_bias: bool = False, bt: int = 256):
     return _ref.gram_norm_fused_ref(x, dy, w, has_bias=has_bias)
 
 
-def _legal_bds(D: int) -> list:
-    """Output-channel tiles the kernel's blocks allow, largest first: D
-    itself, or a multiple of 8 dividing D."""
-    return [d for d in range(D, 0, -1)
-            if D % d == 0 and (d == D or d % 8 == 0)]
+def _as_tuple(v, n: int) -> tuple:
+    return tuple(v) if isinstance(v, (tuple, list)) else (v,) * n
 
 
-def _geometry(x_spatial: tuple, dy_spatial: tuple, k_spatial: tuple):
-    """(W, H', KH, KW) of the kernel's flattened layout; a 1-D conv is the
-    2-D kernel with W = 1."""
-    if len(k_spatial) == 1:
-        return 1, dy_spatial[0], k_spatial[0], 1
-    return x_spatial[1], dy_spatial[0], k_spatial[0], k_spatial[1]
-
-
-@functools.lru_cache(maxsize=256)
-def _autotune_bd(D: int, C: int, x_spatial: tuple, dy_spatial: tuple,
-                 k_spatial: tuple, budget: int = VMEM_BUDGET) -> int:
-    """Output-channel tile for the pe_conv_grad grid: the largest legal
-    tile whose padded VMEM working set (``pe_conv_grad.vmem_bytes``, at
-    the row tile that then fits) stays within the budget; the smallest
-    legal tile when none does."""
-    W, Hp, KH, KW = _geometry(x_spatial, dy_spatial, k_spatial)
-    bds = _legal_bds(D)
-    for bd in bds:
-        th = _pc.row_tile(bd, C, Hp, W, KH, KW, budget)
-        if _pc.vmem_bytes(bd, C, W, th, KH, KW) <= budget:
-            return bd
-    return bds[-1]
-
-
-def pick_bd(D: int, C: int, x_spatial: tuple, dy_spatial: tuple,
-            k_spatial: tuple, budget: int = VMEM_BUDGET) -> int:
-    """Analytic bd choice, overridable with REPRO_PE_CONV_BD (rounded down
-    to a legal tile, see ``_legal_bds``).  The env var is read here,
-    outside the cache, so mid-process sweeps work."""
-    env = os.environ.get("REPRO_PE_CONV_BD")
-    if env:
-        want = max(1, min(int(env), D))
-        legal = _legal_bds(D)
-        return next((d for d in legal if d <= want), legal[-1])
-    return _autotune_bd(D, C, x_spatial, dy_spatial, k_spatial, budget)
-
-
-def pe_conv_grad(x, dy, *, kernel_spatial, stride=1, dilation=1, padding=0,
-                 groups: int = 1):
-    """Pallas path for Algorithm 2, with bd-tiled grid autotuning.  Plain
-    convs (stride=dilation=1, groups=1) hit the kernel; anything else
-    falls back to the XLA grouped-conv lowering (still the paper's
-    algorithm)."""
-    from repro.models import convops
-
-    def _as_tuple(v, n):
-        return tuple(v) if isinstance(v, (tuple, list)) else (v,) * n
-
+def kernel_takes(kernel_spatial, *, stride=1, dilation=1, padding=0,
+                 groups: int = 1) -> bool:
+    """Whether the pe_conv_grad kernel computes this convolution: rank 1
+    or 2, stride 1, undilated, ungrouped, padding below the kernel size
+    (the kernel reads an unpadded capture)."""
     rank = len(kernel_spatial)
-    plain = (groups == 1 and _as_tuple(stride, rank) == (1,) * rank
-             and _as_tuple(dilation, rank) == (1,) * rank)
+    return (rank in (1, 2) and groups == 1
+            and _as_tuple(stride, rank) == (1,) * rank
+            and _as_tuple(dilation, rank) == (1,) * rank
+            and all(0 <= p < k for p, k in
+                    zip(_as_tuple(padding, rank), kernel_spatial)))
+
+
+def pe_conv_grad(x, dy, *, kernel_spatial, padding=0):
+    """The pe_conv_grad kernel for a convolution it takes
+    (:func:`kernel_takes`), with the row tile sized to the VMEM budget;
+    interpret mode off the TPU."""
+    if on_tpu() and jax.config.jax_default_matmul_precision in ONE_BF16_PASS:
+        # The MXU rounds f32 operands to bf16 at this precision.  Rounding
+        # them where they are made lets the compiler keep bf16 copies of
+        # the capture and cotangent for the kernel, as it does for the
+        # grouped convolutions, and halves what the kernel reads.
+        x, dy = x.astype(jnp.bfloat16), dy.astype(jnp.bfloat16)
+    rank = len(kernel_spatial)
+    p = _as_tuple(padding, rank)
     interp = not on_tpu()
-    if plain and rank in (1, 2):
-        p = _as_tuple(padding, rank)
-        if any(p):
-            cfg = [(0, 0), (0, 0)] + [(pi, pi) for pi in p]
-            x = jnp.pad(x, cfg)
-        budget = vmem_budget()
-        x_sp, dy_sp = tuple(x.shape[2:]), tuple(dy.shape[2:])
-        C, k_sp = x.shape[1], tuple(kernel_spatial)
-        bd = pick_bd(dy.shape[1], C, x_sp, dy_sp, k_sp, budget=budget)
-        W, Hp, KH, KW = _geometry(x_sp, dy_sp, k_sp)
-        th = _pc.row_tile(bd, C, Hp, W, KH, KW, budget)
-        if rank == 1:
-            return _pc.pe_conv_grad_1d(x, dy, K=KH, bd=bd, th=th,
-                                       interpret=interp)
-        return _pc.pe_conv_grad_2d(x, dy, KH=KH, KW=KW, bd=bd, th=th,
-                                   interpret=interp)
-    return convops.pe_conv_grad(x, dy, kernel_spatial=kernel_spatial,
-                                stride=stride, dilation=dilation,
-                                padding=padding, groups=groups, impl="fgc")
+    eb = _pc.examples_per_step(_pc.operand_dtype(x.dtype, dy.dtype))
+    if rank == 1:
+        th = _pc.row_tile(x.shape[2], kernel_spatial[0], 1, 1,
+                          vmem_budget(), eb)
+        return _pc.pe_conv_grad_1d(x, dy, K=kernel_spatial[0], padding=p[0],
+                                   th=th, interpret=interp)
+    KH, KW = kernel_spatial
+    th = _pc.row_tile(x.shape[2], KH, KW, x.shape[3], vmem_budget(), eb)
+    return _pc.pe_conv_grad_2d(x, dy, KH=KH, KW=KW, padding=p, th=th,
+                               interpret=interp)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,

@@ -1,21 +1,29 @@
 """Pallas TPU kernel: per-example convolution weight gradients.
 
 The paper's Algorithm 2 as a direct TPU kernel instead of a grouped-conv
-lowering: for each example b (and output-channel tile),
+lowering: for each example b,
 
-    δh[b,d,c,kh,kw] = Σ_{h,w} x[b,c,h+kh,w+kw] δy[b,d,h,w]
+    δh[b,d,c,kh,kw] = Σ_{i,j} x[b,c,i,j] δy[b,d,i-kh+P,j-kw+Q]
 
-The wrapper lays both operands out so that every kernel window is a
-contiguous row slice.  x goes channels-last and row-flattened, (H·W, C);
-δy goes to (D, H'·W) with its columns zero-padded from W' to W.  The window
-(kh, kw) of output position p = h·W + w is then x row p + kh·W + kw, and
-positions with w ≥ W' (which wrap into the next image row) meet zeros in
-δy.  Each grid cell (b, d-tile, row-tile) issues KH·KW MXU matmuls
-(bd, rows)·(rows, C) over static row offsets — no gather, no in-kernel
-reshape — and accumulates the (KH·KW, bd, C) output tile over the row
-tiles.  Stride/dilation/padding are handled by the wrapper in ops.py
-(padding x), which falls back to the XLA grouped-conv lowering for exotic
-configurations.  The 1-D kernel is the 2-D one with W = 1.
+as KH·KW MXU matmuls per example and tile of input rows, reading the
+operands where they lie.  On the TPU a convolution's activations and
+cotangents are kept with the image's rows and columns outermost and
+(examples, channels) as the tiled minor pair, i.e. as (H, W, B, C)
+arrays; the kernel takes them in that layout, so the transposes in front
+of it are layout changes and no copy of a capture is made.  Each grid
+step holds ``th`` rows of x and the th+KH-1 rows of δy they meet, for
+one tile's examples (8 in f32, 16 in bf16), flattened to positions
+p = row·Wx + col with Wx = W+KW-1 columns, the ones past the image
+zeroed.  δy's window is copied into a VMEM scratch at the row its first
+output row belongs to, so the first and last row tiles, whose window is
+clamped to the image, line up like the others, and its rows outside the
+image are zeroed there: tap (kh, kw) then pairs x position p with window
+position p + (KH-1-kh)·Wx + Q-kw in every grid step, and one body serves
+them all.  One example's positions (a bf16 pair's, which share 32-bit
+words) are every eighth 32-bit row of the flattened block, read with a
+strided load at the tap's static offset.  Channels are tiled by 128 lanes
+(a narrower operand is padded to 128 in VMEM and its padding never
+reaches the stored result).  The 1-D kernel is the 2-D one with W = 1.
 """
 from __future__ import annotations
 
@@ -23,100 +31,191 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.mxu import nn
 
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
-
-
-def _layout(W: int, th: int, KH: int, KW: int) -> tuple[int, int]:
-    """(rw, L): the δy tile's lane-padded row count and the x window rows
-    one row tile of ``th`` output rows reads."""
-    rw = _round_up(th * W, 128)
-    return rw, _round_up(rw + (KH - 1) * W + KW - 1, 8)
+WORDS = 8       # 32-bit sublanes of a tile: 8 f32 or 16 bf16 examples
+LANES = 128     # channels per grid step
+# Scoped VMEM the kernel may use; row tiles are sized to a budget below it.
+VMEM_LIMIT = 100 << 20
 
 
-def vmem_bytes(bd: int, C: int, W: int, th: int, KH: int, KW: int) -> int:
-    """VMEM of one grid step at 4 bytes an element, (8, 128)-tile padding
-    and double-buffering included: the x window, the δy tile and the
-    output tile."""
-    rw, L = _layout(W, th, KH, KW)
-    c_lanes = _round_up(C, 128)
-    x_tile = L * c_lanes
-    dy_tile = _round_up(bd, 8) * rw
-    out_tile = KH * KW * _round_up(bd, 8) * c_lanes
-    return 2 * 4 * (x_tile + dy_tile + out_tile)
+def operand_dtype(x_dtype, dy_dtype):
+    """The type the kernel multiplies in: bf16 if both operands are bf16,
+    else f32."""
+    both_bf16 = x_dtype == dy_dtype == jnp.bfloat16
+    return jnp.bfloat16 if both_bf16 else jnp.float32
 
 
-def row_tile(bd: int, C: int, Hp: int, W: int, KH: int, KW: int,
-             budget: int) -> int:
-    """Output rows per grid step: all of them (rounded up to 8) when they
-    fit ``budget``, else halved down to a multiple of 8."""
-    th = _round_up(Hp, 8)
-    while th > 8 and vmem_bytes(bd, C, W, th, KH, KW) > budget:
-        th = _round_up(th // 2, 8)
-    return th
+def examples_per_step(dtype) -> int:
+    """Examples a grid step holds: a tile's 32-bit sublanes of them."""
+    return WORDS * 4 // jnp.dtype(dtype).itemsize
 
 
-def _kernel(x_ref, dy_ref, o_ref, *, KH: int, KW: int, W: int, rw: int):
-    @pl.when(pl.program_id(2) == 0)
+def _out_buffers(eb: int, KH: int, KW: int) -> int:
+    """Output buffers: two, so a block's write-back overlaps the next
+    block's products, unless one block alone takes over 16 MiB (a large
+    kernel's taps)."""
+    return 1 if 4 * eb * KH * KW * LANES * LANES > 16 << 20 else 2
+
+
+def vmem_bytes(th: int, KH: int, KW: int, W: int, eb: int = WORDS) -> int:
+    """VMEM of one grid step at ``th`` input rows and ``eb`` examples (8
+    f32 or 16 bf16): the double-buffered x tile and δy window, the δy
+    scratch, the f32 output tile(s), and the strided operands in
+    flight."""
+    Wx = W + KW - 1
+    n = th * Wx
+    blocks = (th + th + KH - 1) * Wx * WORDS * LANES
+    scratch = (th + 3 * KH - 2) * Wx * WORDS * LANES
+    out = eb * KH * KW * LANES * LANES * _out_buffers(eb, KH, KW)
+    return 4 * (2 * blocks + scratch + out + 4 * n * LANES)
+
+
+def row_tile(H: int, KH: int, KW: int, W: int, budget: int,
+             eb: int = WORDS) -> int:
+    """Input rows per grid step: the largest divisor of ``H`` whose
+    working set fits ``budget`` (1 when none does)."""
+    for th in range(H, 0, -1):
+        if H % th == 0 and vmem_bytes(th, KH, KW, W, eb) <= budget:
+            return th
+    return 1
+
+
+def _examples(ref2, pos, n, k, packed):
+    """The examples that word row ``k`` of ``n`` consecutive positions
+    from ``pos`` holds, as the MXU's operand type: one f32 example, or a
+    bf16 pair (rows 2k, 2k+1 share a 32-bit word).  Written with lax ops:
+    the kernel body is traced once per layer shape as the step is traced,
+    and jnp's wrappers each trace a jit of their own."""
+    u = ref2[pl.ds(lax.add(k, pos * WORDS), n, stride=WORDS), :]
+    if not packed:
+        return [u]
+    hi = lax.bitwise_and(u, np.uint32(0xFFFF0000))
+    return [lax.convert_element_type(pltpu.bitcast(w, jnp.float32),
+                                     jnp.bfloat16)
+            for w in (lax.shift_left(u, np.uint32(16)), hi)]
+
+
+def _kernel(x_ref, dy_ref, o_ref, w_ref, *, KH, KW, P, Q, H, W, Ho):
+    th, Wx = x_ref.shape[:2]
+    R, Wo = dy_ref.shape[:2]
+    U = KH - 1 - P
+    r = pl.program_id(3)
+
+    @pl.when(r == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    dy = dy_ref[0, 0]                                   # (bd, rw)
-    for kh in range(KH):
-        for kw in range(KW):
-            xs = x_ref[0, 0, pl.ds(kh * W + kw, rw), :]   # (rw, C)
-            o_ref[0, kh * KW + kw] += nn(dy, xs)
+    if Wx > W:
+        x_ref[:, W:] = jnp.zeros((th, Wx - W) + x_ref.shape[2:], x_ref.dtype)
+    # The window's row m holds output row r*th - U + m and lies at scratch
+    # row 1 + U + m; the block holds output rows from its clamped start.
+    start = jnp.clip(r * th - U, 0, max(Ho - R, 0))
+    if Wx > Wo:
+        w_ref[:, Wo:] = jnp.zeros((w_ref.shape[0], Wx - Wo) + w_ref.shape[2:],
+                                  w_ref.dtype)
+    w_ref[pl.ds(1 + 2 * U + start - r * th, R), :Wo] = dy_ref[...]
+    for t in range(H // th):    # zero the window's rows off the image
+        lo = min(max(U - t * th, 0), R)
+        hi = max(min(Ho + U - t * th, R), lo)
+        if (lo, hi) == (0, R):
+            continue
+
+        @pl.when(r == t)
+        def _edge(lo=lo, hi=hi):
+            for a, b in ((0, lo), (hi, R)):
+                if b > a:
+                    w_ref[1 + U + a:1 + U + b] = jnp.zeros(
+                        (b - a,) + w_ref.shape[1:], w_ref.dtype)
+
+    packed = x_ref.dtype == jnp.bfloat16
+    if packed:
+        x_ref, w_ref = x_ref.bitcast(jnp.uint32), w_ref.bitcast(jnp.uint32)
+    x2 = x_ref.reshape(th * Wx * WORDS, LANES)
+    w2 = w_ref.reshape(w_ref.shape[0] * Wx * WORDS, LANES)
+    bc, bd = o_ref.shape[2], o_ref.shape[3]
+    # x's last KW-1 positions are zeroed columns, which no tap needs.
+    n = th * Wx - (KW - 1)
+
+    def word_row(k, carry):
+        xts = [lax.transpose(g, (1, 0)) for g in _examples(x2, 0, n, k, packed)]
+        for kh in range(KH):
+            for kw in range(KW):
+                off = (1 + U + KH - 1 - kh) * Wx + Q - kw
+                ds = _examples(w2, off, n, k, packed)
+                for i, (g, d) in enumerate(zip(xts, ds)):
+                    acc = nn(g, d)
+                    if acc.shape != (bc, bd):
+                        acc = lax.slice(acc, (0, 0), (bc, bd))
+                    at = (lax.add(lax.mul(k, len(ds)), i), kh * KW + kw)
+                    o_ref[at] = lax.add(o_ref[at], acc)
+        return carry
+
+    lax.fori_loop(0, WORDS, word_row, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("KH", "KW", "bd", "th", "interpret"))
-def pe_conv_grad_2d(x, dy, *, KH: int, KW: int, bd: int = 0, th: int = 0,
+@functools.partial(jax.jit, static_argnames=("KH", "KW", "padding", "th",
+                                             "interpret"))
+def pe_conv_grad_2d(x, dy, *, KH: int, KW: int, padding=(0, 0), th: int = 0,
                     interpret: bool = True):
-    """x (B,C,H,W), dy (B,D,H',W') -> (B,D,C,KH,KW) fp32; ``bd`` output
-    channels (D or a multiple of 8 dividing D) and ``th`` output rows (a
-    multiple of 8) per grid step, all of them when 0."""
+    """x (B,C,H,W), dy (B,D,H',W') -> (B,D,C,KH,KW) fp32, for a stride-1,
+    undilated, ungrouped convolution with ``padding`` (P, Q) rows and
+    columns on each side, 0 <= P < KH and 0 <= Q < KW.  ``th`` input rows
+    (a divisor of H) per grid step, all of them when 0.  bf16 operands
+    (both) are multiplied as bf16 on the MXU, anything else as f32; the
+    sums are f32."""
     B, C, H, W = x.shape
-    _, D, Hp, Wp = dy.shape
-    bd = bd or D
-    th = th or _round_up(Hp, 8)
-    assert D % bd == 0 and th % 8 == 0
-    n_r = -(-Hp // th)
-    rw, L = _layout(W, th, KH, KW)
-    # δy: (B, n_r, D, rw), each row tile flattened and lane-padded.
-    g = jnp.pad(dy, ((0, 0), (0, 0), (0, n_r * th - Hp), (0, W - Wp)))
-    g = g.reshape(B, D, n_r, th * W)
-    g = jnp.pad(g, ((0, 0), (0, 0), (0, 0), (0, rw - th * W)))
-    g = g.transpose(0, 2, 1, 3)
-    # x: (B, n_r, L, C) overlapping row windows of the flattened image.
-    xf = x.transpose(0, 2, 3, 1).reshape(B, H * W, C)
-    xf = jnp.pad(xf, ((0, 0), (0, (n_r - 1) * th * W + L - H * W), (0, 0)))
-    xw = jnp.stack([xf[:, r * th * W: r * th * W + L] for r in range(n_r)],
-                   axis=1)
+    _, D, Ho, Wo = dy.shape
+    P, Q = padding
+    assert 0 <= P < KH and 0 <= Q < KW, (padding, KH, KW)
+    assert (Ho, Wo) == (H + 2 * P - KH + 1, W + 2 * Q - KW + 1)
+    th = th or H
+    assert H % th == 0, (H, th)
+    U, R, Wx = KH - 1 - P, th + KH - 1, W + KW - 1
+    dtype = operand_dtype(x.dtype, dy.dtype)
+    eb = examples_per_step(dtype)
+    nb, nd, nc = -(-B // eb), -(-D // LANES), -(-C // LANES)
+    E = pl.Element
+    b_blk = E(eb, (0, nb * eb - B))
+    xt = x.astype(dtype).transpose(2, 3, 0, 1)
+    dyt = dy.astype(dtype).transpose(2, 3, 0, 1)
     out = pl.pallas_call(
-        functools.partial(_kernel, KH=KH, KW=KW, W=W, rw=rw),
-        grid=(B, D // bd, n_r),
+        functools.partial(_kernel, KH=KH, KW=KW, P=P, Q=Q, H=H, W=W, Ho=Ho),
+        grid=(nb, nd, nc, H // th),
         in_specs=[
-            pl.BlockSpec((1, 1, L, C), lambda b, d, r: (b, r, 0, 0)),
-            pl.BlockSpec((1, 1, bd, rw), lambda b, d, r: (b, r, d, 0)),
+            pl.BlockSpec((E(th), E(Wx, (0, Wx - W)), b_blk,
+                          E(LANES, (0, nc * LANES - C))),
+                         lambda e, d, c, r: (r * th, 0, e * eb, c * LANES)),
+            pl.BlockSpec((E(R, (0, max(R - Ho, 0))), E(Wo), b_blk,
+                          E(LANES, (0, nd * LANES - D))),
+                         lambda e, d, c, r: (
+                             jnp.clip(r * th - U, 0, max(Ho - R, 0)), 0,
+                             e * eb, d * LANES)),
         ],
-        out_specs=pl.BlockSpec((1, KH * KW, bd, C),
-                               lambda b, d, r: (b, 0, d, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH * KW, D, C), jnp.float32),
+        out_specs=pl.BlockSpec((eb, KH * KW, min(C, LANES), min(D, LANES)),
+                               lambda e, d, c, r: (e, 0, c, d),
+                               pipeline_mode=(
+                                   None if _out_buffers(eb, KH, KW) == 2
+                                   else pl.Buffered(1))),
+        out_shape=jax.ShapeDtypeStruct((B, KH * KW, C, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1 + 2 * U + R, Wx, eb, LANES), dtype)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="pe_conv_grad",
-    )(xw, g)
-    return out.reshape(B, KH, KW, D, C).transpose(0, 3, 4, 1, 2)
+    )(xt, dyt)
+    return out.reshape(B, KH, KW, C, D).transpose(0, 4, 3, 1, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("K", "bd", "th", "interpret"))
-def pe_conv_grad_1d(x, dy, *, K: int, bd: int = 0, th: int = 0,
+@functools.partial(jax.jit, static_argnames=("K", "padding", "th",
+                                             "interpret"))
+def pe_conv_grad_1d(x, dy, *, K: int, padding: int = 0, th: int = 0,
                     interpret: bool = True):
     """x (B,C,T), dy (B,D,T') -> (B,D,C,K); stride=dilation=1, groups=1."""
-    out = pe_conv_grad_2d(x[..., None], dy[..., None], KH=K, KW=1, bd=bd,
-                          th=th, interpret=interpret)
+    out = pe_conv_grad_2d(x[..., None], dy[..., None], KH=K, KW=1,
+                          padding=(padding, 0), th=th, interpret=interpret)
     return out[..., 0]

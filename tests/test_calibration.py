@@ -110,7 +110,7 @@ def _drive(engine, params0, batch_fn, steps=STEPS, feed_seconds=None):
 def test_calibration_file_round_trip_bit_identical(tmp_path):
     calib = calibrate.injected(
         mesh="data:2", collective_bytes_per_second=3.5e9,
-        kernels={"pe_conv_grad": {"vmem_budget": 1 << 20, "bd": 16}})
+        kernels={"pe_conv_grad": {"vmem_budget": 1 << 20, "th": 16}})
     path = str(tmp_path / "c.json")
     calibrate.save_calibration(path, calib)
     got = calibrate.load_calibration(path, expect_mesh="data:2")
@@ -468,7 +468,7 @@ def test_mutation_every_error_is_a_named_calibration_error():
 def test_vmem_budget_precedence(monkeypatch):
     assert kops.vmem_budget() == kops.VMEM_BUDGET      # analytic default
     calib = calibrate.injected(
-        kernels={"pe_conv_grad": {"vmem_budget": 4 << 20, "bd": 8}})
+        kernels={"pe_conv_grad": {"vmem_budget": 4 << 20, "th": 8}})
     calibrate.register(calib)
     assert kops.vmem_budget() == 4 << 20               # measured winner
     monkeypatch.setenv("REPRO_VMEM_BUDGET", str(1 << 20))
@@ -486,7 +486,7 @@ def test_quick_harness_measures_live_hardware():
     assert calib.hbm_bytes_per_second > 0
     pe = calib.kernels["pe_conv_grad"]
     assert str(pe["vmem_budget"]) in pe["sweep"]       # winner from grid
-    assert pe["bd"] >= 1
+    assert pe["th"] >= 1
     # round-trips through its own serialization
     assert calibrate.Calibration.from_json(calib.to_json()) == calib
 

@@ -78,6 +78,33 @@ def test_pe_conv_grad_2d_kernel(shape):
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("case", [
+    # (B, C, D, H, W, K, P, th): several row tiles with padding, channels
+    # over one 128-lane tile and not a multiple of it, a partial block of
+    # examples
+    (5, 130, 136, 12, 7, 3, 1, 4),
+    (3, 5, 4, 9, 6, 5, 2, 3),       # 5x5, padding 2
+    (2, 3, 6, 6, 5, 3, 1, 1),       # one row a step
+    (3, 4, 5, 8, 8, 3, 0, 2),       # no padding
+], ids=["lanes", "k5", "th1", "valid"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pe_conv_grad_2d_kernel_tiles(case, dtype):
+    """Row tiles, padding, lane tiles and partial blocks against the
+    oracle on the zero-padded input; bf16 operands are multiplied exactly,
+    so only the sums' f32 rounding separates the two."""
+    B, C, D, H, W, K, P, th = case
+    rng = np.random.RandomState(sum(case))
+    x = jnp.array(rng.randn(B, C, H, W), dtype)
+    dy = jnp.array(rng.randn(B, D, H + 2 * P - K + 1, W + 2 * P - K + 1),
+                   dtype)
+    got = pe_conv_grad_2d(x, dy, KH=K, KW=K, padding=(P, P), th=th,
+                          interpret=True)
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, 0), (P, P), (P, P)))
+    want = ref.pe_conv_grad_2d_ref(xp, dy.astype(jnp.float32), K, K)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-4)
+
+
 @pytest.mark.parametrize("cfg", [
     # (B, T, S, H, Hkv, hd, causal, bq, bk)
     (2, 64, 64, 4, 2, 16, True, 32, 32),

@@ -1,7 +1,6 @@
 """The per-layer execution planner: cost-model crossovers, plan caching,
 the one-forward/one-backward steady state, and auto == naive exactness on
 a CNN config and a tied-embedding LM config."""
-import os
 
 import numpy as np
 import pytest
@@ -231,29 +230,26 @@ def test_dense_norm_and_contrib_methods():
 
 
 # ---------------------------------------------------------------------------
-# bd-tile autotuning for the per-example conv-grad kernel
+# Row-tile autotuning for the per-example conv-grad kernel
 
 
 def test_pe_conv_bd_autotune():
+    """The kernel's tile: output rows per grid step, sized to the VMEM
+    budget (its channel tiles are fixed at 128 lanes)."""
     from repro.kernels import pe_conv_grad as pc
-    bd = kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3))
-    assert 64 % bd == 0 and (bd == 64 or bd % 8 == 0)
-    # padded working set must fit the budget
-    th = pc.row_tile(bd, 16, 30, 32, 3, 3, kops.VMEM_BUDGET)
-    assert pc.vmem_bytes(bd, 16, 32, th, 3, 3) <= kops.VMEM_BUDGET
-    # a tiny budget forces tiling below full D
-    small = kops.pick_bd(512, 16, (32, 32), (30, 30), (3, 3),
-                         budget=1 << 20)
-    assert small < 512 and 512 % small == 0 and small % 8 == 0
-    # env override wins, rounded down to a legal tile (a multiple of 8
-    # dividing D, or D)
-    try:
-        os.environ["REPRO_PE_CONV_BD"] = "16"
-        assert kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3)) == 16
-        os.environ["REPRO_PE_CONV_BD"] = "31"  # not legal -> 16
-        assert kops.pick_bd(64, 16, (32, 32), (30, 30), (3, 3)) == 16
-    finally:
-        del os.environ["REPRO_PE_CONV_BD"]
+    for eb in (8, 16):
+        th = pc.row_tile(30, 3, 3, 32, kops.VMEM_BUDGET, eb)
+        # a divisor of the output rows whose working set fits the budget
+        assert 30 % th == 0
+        assert pc.vmem_bytes(th, 3, 3, 32, eb) <= kops.VMEM_BUDGET
+        # a smaller budget never gives a larger tile
+        small = pc.row_tile(30, 3, 3, 32, 16 << 20, eb)
+        assert 30 % small == 0 and small <= th
+    # VGG16's conv1 at 256 px needs several row tiles; its conv8 does not
+    assert pc.row_tile(256, 3, 3, 256, kops.VMEM_BUDGET, 16) < 256
+    assert pc.row_tile(32, 3, 3, 32, kops.VMEM_BUDGET, 16) == 32
+    # a budget nothing fits falls back to one row per step
+    assert pc.row_tile(30, 3, 3, 32, 1 << 10) == 1
 
 
 def test_planner_backward_sum_phase_reachable():

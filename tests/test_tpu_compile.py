@@ -71,27 +71,30 @@ def test_gram_norm_compiles(one_chip, layer):
              [((B, T, Di), F32), ((B, T, Do), F32)], one_chip)
 
 
-# (C, D, input side after padding, output side) of VGG16 convs at 256 px.
-VGG_CONV = {"conv1": (64, 64, 258, 256), "conv4": (128, 256, 66, 64),
-            "conv8": (512, 512, 34, 32)}
+# (C, D, image side) of VGG16's 3x3, padding-1 convs at 256 px, at the
+# batch of the vgg16.flat.b32 cell, with the row tile the wrapper picks.
+VGG_CONV = {"conv1": (64, 64, 256), "conv3": (128, 128, 128),
+            "conv8": (512, 512, 32)}
 
 
 @pytest.mark.parametrize("layer", sorted(VGG_CONV))
-def test_pe_conv_grad_2d_compiles(one_chip, layer):
-    C, D, S, So = VGG_CONV[layer]
-    B = 16
-    bd = ops.pick_bd(D, C, (S, S), (So, So), (3, 3))
-    th = pc.row_tile(bd, C, So, S, 3, 3, ops.VMEM_BUDGET)
-    _compile(lambda x, dy: pc.pe_conv_grad_2d(x, dy, KH=3, KW=3, bd=bd,
-                                              th=th, interpret=False),
-             [((B, C, S, S), F32), ((B, D, So, So), F32)], one_chip)
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_pe_conv_grad_2d_compiles(one_chip, layer, dtype):
+    """bf16 is what the wrapper hands the kernel at the default matmul
+    precision; f32 under ``highest``."""
+    C, D, S = VGG_CONV[layer]
+    B = 32
+    th = pc.row_tile(S, 3, 3, S, ops.VMEM_BUDGET,
+                     pc.examples_per_step(dtype))
+    _compile(lambda x, dy: pc.pe_conv_grad_2d(
+        x, dy, KH=3, KW=3, padding=(1, 1), th=th, interpret=False),
+        [((B, C, S, S), dtype), ((B, D, S, S), dtype)], one_chip)
 
 
 def test_pe_conv_grad_1d_compiles(one_chip):
     B, C, D, T, K = 16, 256, 256, 1026, 3
-    bd = ops.pick_bd(D, C, (T,), (T - K + 1,), (K,))
-    th = pc.row_tile(bd, C, T - K + 1, 1, K, 1, ops.VMEM_BUDGET)
-    _compile(lambda x, dy: pc.pe_conv_grad_1d(x, dy, K=K, bd=bd, th=th,
+    th = pc.row_tile(T, K, 1, 1, ops.VMEM_BUDGET)
+    _compile(lambda x, dy: pc.pe_conv_grad_1d(x, dy, K=K, th=th,
                                               interpret=False),
              [((B, C, T), F32), ((B, D, T - K + 1), F32)], one_chip)
 
@@ -119,13 +122,13 @@ def test_bf16_kernels_compile_at_highest_precision(one_chip):
     """The Gram and conv kernels on bf16 captures inside
     ``jax.default_matmul_precision("highest")``."""
     B, T, Di, Do = VGG_DENSE["conv10"]
-    C, D, S, So = VGG_CONV["conv8"]
+    C, D, S = VGG_CONV["conv8"]
     with jax.default_matmul_precision("highest"):
         _compile(lambda x, dy, w: gram_norm.gram_norm_fused(
             x, dy, w, has_bias=True, interpret=False),
             [((B, T, Di), BF16), ((B, T, Do), BF16), ((B,), F32)], one_chip)
         _compile(lambda x, dy: gram_norm.gram_norm(x, dy, interpret=False),
                  [((B, T, Di), BF16), ((B, T, Do), BF16)], one_chip)
-        _compile(lambda x, dy: pc.pe_conv_grad_2d(x, dy, KH=3, KW=3, bd=64,
-                                                  th=8, interpret=False),
-                 [((B, C, S, S), BF16), ((B, D, So, So), BF16)], one_chip)
+        _compile(lambda x, dy: pc.pe_conv_grad_2d(
+            x, dy, KH=3, KW=3, padding=(1, 1), th=8, interpret=False),
+            [((B, C, S, S), BF16), ((B, D, S, S), BF16)], one_chip)
