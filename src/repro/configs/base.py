@@ -60,6 +60,8 @@ class ModelConfig:
     cnn_channels: tuple = ()
     cnn_kernel: int = 3
     img_size: int = 224
+    cnn_avgpool: int = 0           # side of torchvision's adaptive average
+                                   # pool before the classifier; 0: none
     n_classes: int = 1000
     # serving
     prefill_last_only: bool = False   # head matmul on last position only

@@ -277,7 +277,7 @@ class DPConfig:
         """Keyword arguments for :func:`repro.core.costmodel.get_plan`."""
         return dict(norm_method=self.norm.dense, embed_method=self.norm.embed,
                     conv_norm=self.norm.conv, mem_budget=self.norm.mem_budget,
-                    overrides=self.overrides,
+                    conv_impl=self.norm.conv_impl, overrides=self.overrides,
                     clip_mode=self.clipping.mode,
                     clip_fused=self.clipping.fused)
 

@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.tapper import LayerMeta, get_subtree, probe
+from repro.launch.mesh import DATA_AXIS_NAMES
 
 GRAM_CHUNK = 1024
 STREAM_MEM_BUDGET = 2 << 30  # bytes of per-example-grad scratch we tolerate
@@ -117,9 +118,6 @@ ANALYTIC_FALLBACK = {
 # values by their historical names.
 COLLECTIVE_FLOPS_PER_BYTE = ANALYTIC_FALLBACK["collective_flops_per_byte"]
 HBM_FLOPS_PER_BYTE = ANALYTIC_FALLBACK["hbm_flops_per_byte"]
-# Mesh axes treated as pure data parallelism (batch-sharded); every other
-# axis is model parallelism.
-DATA_AXIS_NAMES = ("pod", "data", "batch")
 
 
 # ---------------------------------------------------------------------------
@@ -628,7 +626,7 @@ def _plan_layer(name: str, meta: LayerMeta, cap_sh: dict, dy_sh,
                 mem_budget: int, vocab: int | None = None,
                 params_sub=None, mesh: tuple = (),
                 clip_mode: str = "flat",
-                clip_fused: bool = True,
+                clip_fused: bool = True, conv_impl: str = "auto",
                 cc: CostConstants = ANALYTIC_CONSTANTS) -> LayerPlan:
     """Costs for one tap.  Stacked (scanned) applications multiply the
     per-application cost; shared stacked dense/scale layers fold the stack
@@ -790,6 +788,15 @@ def _plan_layer(name: str, meta: LayerMeta, cap_sh: dict, dy_sh,
         K = _prod(st["kernel_shape"][2:])
         g = max(st.get("groups", 1), 1)
         F, Dg = (C // g) * K, D // g
+        # The per-example gradient's own taps, those of the route it takes
+        # (through space to depth, s·ceil(k/s) per axis cropped to k).
+        from repro.models import convops
+        route = convops.pe_conv_route(
+            st["kernel_shape"][2:], C, stride=st.get("stride", 1),
+            dilation=st.get("dilation", 1), padding=st.get("padding", 0),
+            groups=g, impl=conv_impl)
+        Fpe = (C // g) * convops.route_taps(route, st["kernel_shape"][2:],
+                                            st.get("stride", 1))
         # Tensor sharding partitions the output channels: each model
         # shard owns Dg/msh filters per group, contracts its local patch
         # slice for the ghost norm, and psums the partial per-example
@@ -810,10 +817,10 @@ def _plan_layer(name: str, meta: LayerMeta, cap_sh: dict, dy_sh,
                            - _fused_credit(
                                T * (F + Dgl) * g * BYTES * per_ex,
                                ghost_flops))
-            pe_stash = (4.0 * T * F * Dgl * g * per_ex
+            pe_stash = (4.0 * T * Fpe * Dgl * g * per_ex
                         + _move_cost(mem_stash))
-            pe_again = ((4.0 * T * F * Dgl + 2.0 * T * F * Dgl) * g * per_ex
-                        + _scal_cost(B, msh > 1))
+            pe_again = ((4.0 * T * Fpe * Dgl + 2.0 * T * F * Dgl) * g
+                        * per_ex + _scal_cost(B, msh > 1))
             fallback = ("pe" if pe_again < ghost_total
                         and mem_layer <= mem_budget else "ghost")
             if pe_stash < ghost_total and mem_stash <= mem_budget:
@@ -824,7 +831,7 @@ def _plan_layer(name: str, meta: LayerMeta, cap_sh: dict, dy_sh,
             m = conv_norm
             stash = m == "pe" and mem_stash <= mem_budget
         nf = (2.0 * Bl * T * T * (F + Dgl) * g if m == "ghost"
-              else 4.0 * Bl * T * F * Dgl * g) * stack
+              else 4.0 * Bl * T * Fpe * Dgl * g) * stack
         return LayerPlan(name, "conv", m, stash, nf, cf, cf,
                          stash_bytes=mem_stash, fallback_norm=fallback,
                          param_bytes=pbytes, ex_per_dev=Bl,
@@ -1004,7 +1011,8 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
                    conv_norm: str = "auto",
                    mem_budget: int = STREAM_MEM_BUDGET,
                    overrides=None, mesh=None, clip_mode: str = "flat",
-                   clip_fused: bool = True, calibration=None) -> ExecPlan:
+                   clip_fused: bool = True, calibration=None,
+                   conv_impl: str = "auto") -> ExecPlan:
     """Build the per-layer plan from probed shapes.
 
     Fixed ``norm_method`` / ``embed_method`` / ``conv_norm`` override the
@@ -1047,7 +1055,7 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
             conv_norm=ov or conv_norm, mem_budget=mem_budget,
             vocab=_vocab_of(meta, params) if meta.kind == "embed" else None,
             params_sub=psub, mesh=ms, clip_mode=clip_mode,
-            clip_fused=clip_fused, cc=cc)
+            clip_fused=clip_fused, conv_impl=conv_impl, cc=cc)
         by_path.setdefault(meta.path, []).append(name)
 
     total_wgrad = sum(lp.wgrad_flops for lp in layers.values())
@@ -1397,33 +1405,36 @@ def check_plan_matches(plan: ExecPlan, *, fingerprint: str | None = None,
 
 def _opts_tuple(norm_method, embed_method, conv_norm, mem_budget,
                 overrides, mesh, clip_mode="flat", clip_fused=True,
-                calibration=None) -> tuple:
+                calibration=None, conv_impl="auto") -> tuple:
     ms = mesh_axes(mesh)
     calib = _resolve_calibration(calibration, ms)
     return (norm_method, embed_method, conv_norm, mem_budget,
             normalize_overrides(overrides), ms,
             (str(clip_mode), bool(clip_fused)),
-            "" if calib is None else calib.digest())
+            "" if calib is None else calib.digest(), conv_impl)
 
 
 def plan_fingerprint(apply_fn, params, batch, *, norm_method: str = "auto",
                      embed_method: str = "auto", conv_norm: str = "auto",
                      mem_budget: int = STREAM_MEM_BUDGET,
                      overrides=None, mesh=None, clip_mode: str = "flat",
-                     clip_fused: bool = True, calibration=None) -> str:
+                     clip_fused: bool = True, calibration=None,
+                     conv_impl: str = "auto") -> str:
     """The fingerprint :func:`get_plan` would key this request on — same
     knob normalization, no probe."""
     return model_fingerprint(
         apply_fn, params, batch,
         _opts_tuple(norm_method, embed_method, conv_norm, mem_budget,
-                    overrides, mesh, clip_mode, clip_fused, calibration))
+                    overrides, mesh, clip_mode, clip_fused, calibration,
+                    conv_impl))
 
 
 def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
              embed_method: str = "auto", conv_norm: str = "auto",
              mem_budget: int = STREAM_MEM_BUDGET,
              overrides=None, mesh=None, clip_mode: str = "flat",
-             clip_fused: bool = True, calibration=None) -> ExecPlan:
+             clip_fused: bool = True, calibration=None,
+             conv_impl: str = "auto") -> ExecPlan:
     """Cached planner entry point.  The anchor reference pinned in the
     cached plan keeps ``id(apply_fn.__self__)`` stable for the entry's
     lifetime, so a recycled id can never alias a different model.  A
@@ -1437,7 +1448,8 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
     measured constants fails safe exactly like one built from stale
     code."""
     opts = _opts_tuple(norm_method, embed_method, conv_norm, mem_budget,
-                       overrides, mesh, clip_mode, clip_fused, calibration)
+                       overrides, mesh, clip_mode, clip_fused, calibration,
+                       conv_impl)
     ov, ms = opts[4], opts[5]
     key = plan_cache_key(apply_fn, params, batch, opts)
     plan = _PLAN_CACHE.get(key)
@@ -1458,7 +1470,7 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
             cand_opts = _opts_tuple(
                 norm_method, embed_method, conv_norm, mem_budget,
                 overrides, tuple(cand.mesh), clip_mode, clip_fused,
-                calibration)
+                calibration, conv_impl)
             if cand.fingerprint == model_fingerprint(apply_fn, params,
                                                      batch, cand_opts):
                 check_plan_matches(cand, mesh=ms)
@@ -1469,7 +1481,7 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
             norm_method=norm_method, embed_method=embed_method,
             conv_norm=conv_norm, mem_budget=mem_budget, overrides=ov,
             mesh=ms, clip_mode=clip_mode, clip_fused=clip_fused,
-            calibration=calibration)
+            calibration=calibration, conv_impl=conv_impl)
         plan = dataclasses.replace(plan, fingerprint=fp, batch_sig=sig)
     object.__setattr__(plan, "_anchor", getattr(apply_fn, "__self__",
                                                 apply_fn))

@@ -50,6 +50,7 @@ check.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable
@@ -707,6 +708,14 @@ class PrivacyEngine:
 
         return jax.tree_util.tree_map(leaf_sh, self._opt_spec)
 
+    def _mesh_context(self):
+        """The engine's mesh as JAX's current mesh while the step is traced
+        and dispatched, so per-example kernels split their work over the
+        data axes (``kernels.ops.per_example``)."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
     @functools.cached_property
     def _jit_step(self):
         step = self._step_fn()
@@ -760,7 +769,7 @@ class PrivacyEngine:
             with TraceAnnotation("engine.noise_key"):
                 key = self._check_key(key, step)
             clip_state = self._clip_state()
-            with TraceAnnotation("engine.dispatch"):
+            with TraceAnnotation("engine.dispatch"), self._mesh_context():
                 out = self._jit_step(params, opt, batch, key, clip_state)
             with TraceAnnotation("engine.absorb_clip_aux"):
                 self._absorb_clip_aux(out[3])

@@ -338,6 +338,7 @@ def clipped_grad_sum_detailed(apply_fn, params, batch, *, l2_clip: float,
             plan = costmodel.get_plan(
                 apply_fn, params, batch, norm_method=norm_method,
                 embed_method=embed_method, conv_norm=conv_norm or "auto",
+                conv_impl=conv_impl,
                 mem_budget=mem_budget or costmodel.STREAM_MEM_BUDGET,
                 overrides=overrides, clip_mode=mode,
                 clip_fused=(clip_policy.fused if clip_policy is not None

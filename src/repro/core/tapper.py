@@ -66,7 +66,8 @@ class PipelineStats:
     (``gram_norm_fused``-backed single passes picked by stale-coefficient
     plans), and ``conv_impls`` the implementation each per-example conv
     gradient took (``pallas`` for the MXU kernel, ``taps`` for per-tap
-    dots, ``fgc``, ``bgc``); they are not part of :meth:`snapshot`, which
+    dots, ``s2d_pallas`` and ``s2d_taps`` for either after space to depth,
+    ``fgc``, ``bgc``); they are not part of :meth:`snapshot`, which
     covers only the whole-model pass counters."""
 
     __slots__ = ("forwards", "backwards", "probes", "fused", "conv_impls")

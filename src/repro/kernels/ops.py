@@ -7,6 +7,7 @@ would be too slow.  ``on_tpu()`` centralizes the decision.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -16,6 +17,7 @@ from repro.kernels import flash_attn as _fa
 from repro.kernels import gram_norm as _gn
 from repro.kernels import pe_conv_grad as _pc
 from repro.kernels import ref as _ref
+from repro.launch.mesh import DATA_AXIS_NAMES
 
 # VMEM the pe_conv_grad row tile is sized against (double-buffering
 # included), below the kernel's scoped limit ``pe_conv_grad.VMEM_LIMIT``.
@@ -94,10 +96,27 @@ def kernel_takes(kernel_spatial, *, stride=1, dilation=1, padding=0,
                     zip(_as_tuple(padding, rank), kernel_spatial)))
 
 
+def per_example(fn, *args):
+    """``fn(*args)`` for a function that maps each argument's leading axis,
+    the examples, to its result's.  Under a mesh (the engine traces its
+    step under its own) ``fn`` runs on each device's examples in a
+    ``shard_map`` over the data axes, since the SPMD partitioner cannot
+    split a Pallas kernel."""
+    from jax.sharding import PartitionSpec as P
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = tuple(a for a in DATA_AXIS_NAMES if a in mesh.axis_names)
+    if not axes:
+        return fn(*args)
+    spec = P(axes)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(*args)
+
+
 def pe_conv_grad(x, dy, *, kernel_spatial, padding=0):
     """The pe_conv_grad kernel for a convolution it takes
-    (:func:`kernel_takes`), with the row tile sized to the VMEM budget;
-    interpret mode off the TPU."""
+    (:func:`kernel_takes`), with the row tile sized to the VMEM budget,
+    over each device's examples (:func:`per_example`); interpret mode off
+    the TPU."""
     if on_tpu() and jax.config.jax_default_matmul_precision in ONE_BF16_PASS:
         # The MXU rounds f32 operands to bf16 at this precision.  Rounding
         # them where they are made lets the compiler keep bf16 copies of
@@ -111,12 +130,14 @@ def pe_conv_grad(x, dy, *, kernel_spatial, padding=0):
     if rank == 1:
         th = _pc.row_tile(x.shape[2], kernel_spatial[0], 1, 1,
                           vmem_budget(), eb)
-        return _pc.pe_conv_grad_1d(x, dy, K=kernel_spatial[0], padding=p[0],
-                                   th=th, interpret=interp)
+        return per_example(functools.partial(
+            _pc.pe_conv_grad_1d, K=kernel_spatial[0], padding=p[0], th=th,
+            interpret=interp), x, dy)
     KH, KW = kernel_spatial
     th = _pc.row_tile(x.shape[2], KH, KW, x.shape[3], vmem_budget(), eb)
-    return _pc.pe_conv_grad_2d(x, dy, KH=KH, KW=KW, padding=p, th=th,
-                               interpret=interp)
+    return per_example(functools.partial(
+        _pc.pe_conv_grad_2d, KH=KH, KW=KW, padding=p, th=th,
+        interpret=interp), x, dy)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,

@@ -12,6 +12,10 @@ import os
 
 import jax
 
+# Mesh axes treated as pure data parallelism (batch-sharded); every other
+# axis is model parallelism.
+DATA_AXIS_NAMES = ("pod", "data", "batch")
+
 
 def make_auto_mesh(shape, names, devices=None):
     """``jax.make_mesh`` with every axis ``Auto``: the engine's shardings

@@ -131,7 +131,7 @@ def param_sharding(axes_tree, mesh: Mesh, *, fsdp: bool = False,
 def batch_sharding(batch_abstract, mesh: Mesh):
     """Shard every batch leaf's leading axis over the data axes (the same
     axis-name vocabulary the planner's cost model uses)."""
-    from repro.core.costmodel import DATA_AXIS_NAMES
+    from repro.launch.mesh import DATA_AXIS_NAMES
 
     data_axes = tuple(a for a in DATA_AXIS_NAMES if a in mesh.axis_names)
     if not data_axes:

@@ -2,7 +2,8 @@
 
 AlexNet and VGG16 (Table 1), and the parametric "toy" CNNs of Figures 1–3
 (first-layer channels c0, channel rate r, kernel size K, ReLU after each
-conv, max-pool every 2 convs).  No batch normalization — the paper
+conv, max-pool every 2 convs).  ``cnn_avgpool`` puts torchvision's adaptive
+average pool before the classifier.  No batch normalization — the paper
 excludes it because it mixes examples (per-example gradients become
 ill-defined); dropout is likewise omitted (noted deviation, irrelevant to
 gradient benchmarking).
@@ -30,6 +31,18 @@ VGG16 = [(64, 3, 1, 1, False), (64, 3, 1, 1, True),
 def _maxpool(x, k=2, s=2):
     return lax.reduce_window(x, -jnp.inf, lax.max, (1, 1, k, k),
                              (1, 1, s, s), "VALID")
+
+
+def _adaptive_avgpool(x, out: int):
+    """torchvision's ``AdaptiveAvgPool2d((out, out))``: output i of an axis
+    of n averages inputs floor(i·n/out) to ceil((i+1)·n/out) - 1."""
+    for axis in (2, 3):
+        n = x.shape[axis]
+        x = jnp.stack([
+            lax.slice_in_dim(x, i * n // out, -(-(i + 1) * n // out),
+                             axis=axis).mean(axis)
+            for i in range(out)], axis)
+    return x
 
 
 def _conv_plan(cfg: ModelConfig):
@@ -76,7 +89,8 @@ class CNN:
                 "b": cm.mk(ks[i], (ch,), ("mlp",), dist="zeros",
                            dtype=cfg.jdtype)}
             cin = ch
-        side = _spatial_after(cfg, self.plan, self.pool_k, self.pool_s)
+        side = (cfg.cnn_avgpool
+                or _spatial_after(cfg, self.plan, self.pool_k, self.pool_s))
         feat = cin * side * side
         dims = (feat,) + self.fcs + (cfg.n_classes,)
         for j in range(len(dims) - 1):
@@ -99,6 +113,8 @@ class CNN:
                                       (1, 1, self.pool_k, self.pool_k),
                                       (1, 1, self.pool_s, self.pool_s),
                                       "VALID")
+        if self.cfg.cnn_avgpool:
+            h = _adaptive_avgpool(h, self.cfg.cnn_avgpool)
         return h.reshape(h.shape[0], -1)
 
     def apply(self, params, batch, tp: Tapper):

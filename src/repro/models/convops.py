@@ -18,11 +18,18 @@ paper's (PyTorch) convention.  Works for 1-D/2-D/3-D convolutions.
     (interpret mode off the TPU): the contraction as MXU matmuls over the
     capture's own layout, for the convolutions the kernel takes
     (``kernels.ops.kernel_takes``: rank 1 or 2, stride 1, undilated,
-    ungrouped, padding below the kernel size); ``fgc`` for the others.
+    ungrouped, padding below the kernel size), strided ones through space
+    to depth (below); ``fgc`` for the others.
   * ``impl="auto"`` — on a TPU, for a convolution the kernel takes,
     ``pallas`` where the input has at least ``MXU_MIN_CHANNELS`` channels
     and one batched MXU dot per kernel tap (tallied ``taps``) where it has
     fewer; ``fgc`` otherwise.
+  * Strided rank-1 and rank-2 convolutions (undilated, ungrouped) go
+    through space to depth under ``auto`` on a TPU and under ``pallas``:
+    the padded input split into its stride phases is a stride-1 input of
+    prod(s)·C channels, whose per-example gradients with ⌈k/s⌉ taps per
+    axis the kernel or the per-tap dots form (tallied ``s2d_pallas``,
+    ``s2d_taps``); the s·⌈k/s⌉ taps per axis they give are cropped to k.
 
 Each is validated against the brute-force oracle in ``kernels/ref.py``
 and against autodiff (summed over the batch).
@@ -36,8 +43,9 @@ import jax.numpy as jnp
 from jax import lax
 
 
-# Input channels from which ``impl="auto"`` takes the MXU kernel on a TPU;
-# a narrower convolution takes one batched dot per kernel tap.  The kernel
+# Input channels from which ``impl="auto"`` takes the MXU kernel on a TPU
+# (prod(s)·C for a strided convolution through space to depth); a narrower
+# convolution takes one batched dot per kernel tap.  The kernel
 # pads channels to 128 lanes, at most doubling its reads from 64 on; a
 # narrower capture (an RGB image) the TPU also keeps in another layout,
 # which the kernel would have to copy, 128/C times the size.
@@ -107,6 +115,89 @@ def _pe_conv_grad_taps(x, dy, kernel_spatial, padding):
                                        + tuple(kernel_spatial))
 
 
+def _space_to_depth_takes(kernel_spatial, stride, dilation,
+                          groups: int) -> bool:
+    """Whether a strided convolution's per-example gradients go through
+    space to depth: rank 1 or 2, a stride above 1, undilated, ungrouped."""
+    rank = len(kernel_spatial)
+    return (rank in (1, 2) and groups == 1
+            and _tup(dilation, rank) == (1,) * rank
+            and max(_tup(stride, rank)) > 1)
+
+
+def pe_conv_route(kernel_spatial, channels: int, *, stride=1, dilation=1,
+                  padding=0, groups: int = 1, impl: str = "auto") -> str:
+    """The route :func:`pe_conv_grad` takes under ``impl`` for a
+    convolution of ``channels`` input channels, the key it is tallied
+    under: ``pallas``, ``taps``, ``s2d_pallas``, ``s2d_taps``, ``fgc`` or
+    ``bgc``."""
+    from repro.kernels import ops as kops
+    if impl not in ("auto", "pallas", "fgc", "bgc"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if impl in ("fgc", "bgc"):
+        return impl
+    prefix = ""
+    if _space_to_depth_takes(kernel_spatial, stride, dilation, groups):
+        channels *= int(np.prod(_tup(stride, len(kernel_spatial))))
+        prefix = "s2d_"
+    elif not kops.kernel_takes(kernel_spatial, stride=stride,
+                               dilation=dilation, padding=padding,
+                               groups=groups):
+        return "fgc"
+    if impl == "auto":
+        if not kops.on_tpu():
+            return "fgc"
+        impl = "pallas" if channels >= MXU_MIN_CHANNELS else "taps"
+    return prefix + impl
+
+
+def route_taps(route: str, kernel_spatial, stride=1) -> int:
+    """Kernel taps per example that ``route`` computes: prod(s·⌈k/s⌉)
+    through space to depth, whose padded taps are cropped away, else
+    prod(k)."""
+    if not route.startswith("s2d_"):
+        return int(np.prod(kernel_spatial))
+    s = _tup(stride, len(kernel_spatial))
+    return int(np.prod([si * -(-k // si)
+                        for k, si in zip(kernel_spatial, s)]))
+
+
+def _pe_conv_grad_s2d(x, dy, kernel_spatial, stride, padding, inner):
+    """A strided, undilated, ungrouped convolution's per-example weight
+    gradients through space to depth.  With k = s·q + r (0 <= r < s), tap
+    k of output t reads x_pad[s·(t+q) + r], which is tap q of a stride-1
+    convolution over phase r of x_pad: x_pad (B, C, s·n) becomes x'
+    (B, s·C, n) with n = T'+⌈k/s⌉-1, and ``inner`` (``pallas`` or
+    ``taps``) forms its per-example gradients with ⌈k/s⌉ taps, unpadded;
+    of the s·⌈k/s⌉ taps per axis that gives, those from k on are cropped."""
+    from repro.kernels import ops as kops
+    rank = len(kernel_spatial)
+    s, p = _tup(stride, rank), _tup(padding, rank)
+    q = tuple(-(-k // si) for k, si in zip(kernel_spatial, s))
+    n = tuple(t + qi - 1 for t, qi in zip(dy.shape[2:], q))
+    # Pad by the convolution's padding, then pad or crop each axis to s·n.
+    xp = lax.pad(x, jnp.zeros((), x.dtype), ((0, 0, 0), (0, 0, 0)) + tuple(
+        (pi, si * ni - h - pi, 0)
+        for h, pi, si, ni in zip(x.shape[2:], p, s, n)))
+    B, C = x.shape[:2]
+    split = (B, C) + tuple(d for ni, si in zip(n, s) for d in (ni, si))
+    xs = xp.reshape(split).transpose(
+        (0,) + tuple(3 + 2 * i for i in range(rank)) + (1,)
+        + tuple(2 + 2 * i for i in range(rank)))
+    xs = xs.reshape((B, int(np.prod(s)) * C) + n)
+    if inner == "pallas":
+        g = kops.pe_conv_grad(xs, dy, kernel_spatial=q, padding=0)
+    else:
+        g = _pe_conv_grad_taps(xs, dy, q, 0)
+    # (B, D, *s, C, *q) -> (B, D, C, q0, s0, q1, s1, ...) -> crop to k.
+    D = dy.shape[1]
+    g = g.reshape((B, D) + s + (C,) + q).transpose(
+        (0, 1, 2 + rank) + tuple(d for i in range(rank)
+                                 for d in (3 + rank + i, 2 + i)))
+    g = g.reshape((B, D, C) + tuple(qi * si for qi, si in zip(q, s)))
+    return g[(slice(None),) * 3 + tuple(slice(0, k) for k in kernel_spatial)]
+
+
 def pe_conv_grad(x, dy, *, kernel_spatial, stride=1, dilation=1, padding=0,
                  groups: int = 1, impl: str = "auto"):
     """Per-example convolution-weight gradients (Algorithm 2).
@@ -116,18 +207,13 @@ def pe_conv_grad(x, dy, *, kernel_spatial, stride=1, dilation=1, padding=0,
     """
     from repro.core.tapper import STATS
     from repro.kernels import ops as kops
-    if impl not in ("auto", "pallas", "fgc", "bgc"):
-        raise ValueError(f"unknown impl {impl!r}")
-    if impl in ("auto", "pallas"):
-        plain = kops.kernel_takes(kernel_spatial, stride=stride,
-                                  dilation=dilation, padding=padding,
-                                  groups=groups)
-        if not plain:
-            impl = "fgc"
-        elif impl == "auto":
-            impl = ("fgc" if not kops.on_tpu() else "pallas"
-                    if x.shape[1] >= MXU_MIN_CHANNELS else "taps")
+    impl = pe_conv_route(kernel_spatial, x.shape[1], stride=stride,
+                         dilation=dilation, padding=padding, groups=groups,
+                         impl=impl)
     STATS.conv_impls[impl] += 1
+    if impl.startswith("s2d_"):
+        return _pe_conv_grad_s2d(x, dy, kernel_spatial, stride, padding,
+                                 impl[4:])
     if impl == "pallas":
         return kops.pe_conv_grad(x, dy, kernel_spatial=kernel_spatial,
                                  padding=padding)

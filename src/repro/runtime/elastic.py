@@ -31,7 +31,7 @@ def elastic_mesh_axes(prev_axes, n_devices: int, global_batch: int,
     the engine/planner, which re-plans for the new topology while the
     accountant ledger and the deterministic noise stream continue
     unbroken."""
-    from repro.core.costmodel import DATA_AXIS_NAMES
+    from repro.launch.mesh import DATA_AXIS_NAMES
 
     prev = tuple((str(n), int(s)) for n, s in prev_axes)
     if not prev:

@@ -1,5 +1,7 @@
 """Per-architecture smoke tests (reduced configs, CPU): one DP-ghost train
 gradient + prefill + decode step; asserts shapes and finiteness."""
+import math
+
 import numpy as np
 import pytest
 
@@ -65,3 +67,26 @@ def test_arch_smoke(arch):
     assert logits2.shape == (B, cfg.padded_vocab)
     assert bool(jnp.isfinite(logits2).all())
     assert int(cache["pos"]) > 0
+
+
+@pytest.mark.parametrize("arch,over,count", [
+    ("alexnet", {}, 74_732_328),
+    ("alexnet", {"cnn_avgpool": 6}, 61_100_840),   # torchvision's alexnet
+    ("vgg16", {}, 169_814_824),
+], ids=["alexnet", "alexnet_avgpool6", "vgg16"])
+def test_cnn_parameter_count_at_256px(arch, over, count):
+    model = build_model(get_config(arch).replace(**over))
+    shapes = jax.eval_shape(lambda k: model.init(k)[0], jax.random.PRNGKey(0))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes)) == count
+
+
+@pytest.mark.parametrize("n,out", [(7, 6), (4, 3), (6, 6), (5, 2)])
+def test_adaptive_avgpool_takes_torchvisions_windows(n, out):
+    from repro.models.cnn import _adaptive_avgpool
+    x = np.random.RandomState(n * 10 + out).randn(2, 3, n, n)
+    cut = [(math.floor(i * n / out), math.ceil((i + 1) * n / out))
+           for i in range(out)]
+    want = np.stack([np.stack([x[:, :, a:b, c:d].mean((2, 3))
+                               for c, d in cut], -1) for a, b in cut], -2)
+    got = _adaptive_avgpool(jnp.asarray(x, jnp.float32), out)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)

@@ -3,8 +3,9 @@
 ``conv_impl="auto"`` (NormCfg's default) takes the ``pe_conv_grad`` MXU
 kernel on a TPU for plain convolutions whose input has at least
 ``convops.MXU_MIN_CHANNELS`` channels, one batched dot per kernel tap
-(``taps``) for narrower plain ones, and the grouped-convolution lowering
-(``fgc``) everywhere else.  The choice is read from
+(``taps``) for narrower plain ones, either of them after space to depth
+for strided rank-1 and rank-2 ones (``s2d_pallas``, ``s2d_taps``), and
+the grouped-convolution lowering (``fgc``) everywhere else.  The choice is read from
 ``tapper.STATS.conv_impls``, tallied as the step is traced; here it is
 traced with ``jax.eval_shape``, so a TPU branch can be taken on the CPU.
 The kernel path itself runs in interpret mode against ``fgc`` through the
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 from repro.core import ClipPolicy, NormCfg, clipped_grad_sum_detailed
 from repro.core.tapper import STATS
 from repro.kernels import ops as kops
+from repro.kernels import ref
 from repro.models import convops
 
 F32 = jnp.float32
@@ -48,15 +50,16 @@ def test_auto_takes_the_kernel_for_a_plain_conv_on_tpu(tpu):
 
 
 @pytest.mark.parametrize("case", [
-    # AlexNet conv0: 11x11, stride 4, padding 2
+    # AlexNet conv0's stride and kernel, grouped: space to depth is
+    # for ungrouped convolutions only
     dict(x=(2, 64, 35, 35), dy=(2, 64, 8, 8), kernel=(11, 11), stride=4,
-         padding=2),
+         padding=2, groups=2),
     dict(x=(2, 64, 12, 12), dy=(2, 64, 8, 8), dilation=2),
     dict(x=(2, 64, 12, 12), dy=(2, 64, 10, 10), groups=2),
     # padding as wide as the kernel: the kernel reads unpadded captures
     dict(x=(2, 64, 8, 8), dy=(2, 64, 12, 12), padding=3),
-    # a narrow input with stride 2
-    dict(x=(2, 3, 17, 17), dy=(2, 64, 8, 8), stride=2),
+    # a narrow rank-3 input with stride 2: space to depth is for rank 1, 2
+    dict(x=(2, 3, 9, 9, 9), dy=(2, 8, 4, 4, 4), kernel=(3, 3, 3), stride=2),
 ], ids=["stride4", "dilated", "grouped", "wide_padding", "narrow_strided"])
 def test_auto_keeps_fgc_where_the_kernel_does_not_apply(tpu, case):
     case = dict(case)
@@ -92,6 +95,8 @@ def test_auto_is_fgc_on_the_cpu():
     assert not kops.on_tpu()
     assert _tally((4, 64, 16, 16), (4, 64, 16, 16), padding=1) == {"fgc": 1}
     assert _tally((4, 3, 16, 16), (4, 64, 16, 16), padding=1) == {"fgc": 1}
+    assert _tally((2, 3, 35, 35), (2, 64, 8, 8), kernel=(11, 11), stride=4,
+                  padding=2) == {"fgc": 1}
 
 
 @pytest.mark.parametrize("impl", ["fgc", "bgc", "pallas"])
@@ -102,8 +107,94 @@ def test_explicit_impls_are_honoured(tpu, impl):
 
 
 def test_explicit_pallas_falls_back_to_fgc_for_a_strided_conv(tpu):
+    # a strided dilated conv: neither the kernel nor space to depth
+    assert _tally((2, 64, 17, 17), (2, 64, 7, 7), impl="pallas",
+                  stride=2, dilation=2) == {"fgc": 1}
+    # a plain strided one takes the kernel after space to depth
     assert _tally((2, 64, 17, 17), (2, 64, 8, 8), impl="pallas",
-                  stride=2) == {"fgc": 1}
+                  stride=2) == {"s2d_pallas": 1}
+
+
+@pytest.mark.parametrize("case", [
+    # AlexNet conv0: 11x11, stride 4 -> 16·3 = 48 channels
+    dict(x=(2, 3, 35, 35), dy=(2, 64, 8, 8), kernel=(11, 11), stride=4,
+         padding=2, want="s2d_taps"),
+    # stride 2 on 16 channels -> 64, the kernel's channel floor
+    dict(x=(2, 16, 17, 17), dy=(2, 32, 8, 8), stride=2, want="s2d_pallas"),
+    dict(x=(2, 8, 21), dy=(2, 8, 7), kernel=(5,), stride=3, padding=1,
+         want="s2d_taps"),
+], ids=["alexnet_conv0", "stride2_64ch", "rank1"])
+def test_auto_takes_space_to_depth_for_strided_convs_on_tpu(tpu, case):
+    case = dict(case)
+    x, dy, want = case.pop("x"), case.pop("dy"), case.pop("want")
+    assert _tally(x, dy, **case) == {want: 1}
+
+
+def _strided_oracle(x, dy, kernel, stride, padding):
+    """``kernels/ref.py``'s stride-1 oracle on the padded input against δy
+    spread out by the stride (zeros between its positions)."""
+    (KH, KW), s, p = kernel, stride, padding
+    B, D, Ho, Wo = dy.shape
+    dyd = jnp.zeros((B, D, s * (Ho - 1) + 1, s * (Wo - 1) + 1), F32)
+    dyd = dyd.at[:, :, ::s, ::s].set(dy)
+    xp = jnp.pad(x, ((0, 0), (0, 0), (p, p), (p, p)))
+    xp = xp[:, :, :dyd.shape[2] + KH - 1, :dyd.shape[3] + KW - 1]
+    return ref.pe_conv_grad_2d_ref(xp, dyd, KH, KW)
+
+
+@pytest.mark.parametrize("inner", ["taps", "pallas"])
+@pytest.mark.parametrize("case", [
+    # AlexNet conv0's geometry at a small image
+    dict(x=(2, 3, 35, 35), kernel=(11, 11), stride=4, padding=2),
+    dict(x=(2, 3, 17, 17), kernel=(3, 3), stride=2, padding=0),
+    dict(x=(2, 3, 17, 17), kernel=(3, 3), stride=2, padding=1),
+    dict(x=(2, 3, 16, 16), kernel=(4, 4), stride=2, padding=0),
+    dict(x=(2, 3, 16, 16), kernel=(4, 4), stride=2, padding=1),
+], ids=["alexnet_conv0", "odd_k_pad0", "odd_k_pad1", "even_k_pad0",
+        "even_k_pad1"])
+def test_space_to_depth_matches_fgc_and_the_oracle(monkeypatch, case, inner):
+    """Both inner routes: ``taps`` as ``auto`` takes it on a TPU below the
+    kernel's channel floor, ``pallas`` as an explicit ``pallas`` takes it
+    (the kernel in interpret mode here); each tallied under its key."""
+    kernel, stride, padding = case["kernel"], case["stride"], case["padding"]
+    out = convops.conv_output_spatial(case["x"][2:], kernel, stride, 1,
+                                      padding)
+    rng = np.random.RandomState(sum(kernel) + padding)
+    x = jnp.asarray(rng.randn(*case["x"]), F32)
+    dy = jnp.asarray(rng.randn(case["x"][0], 6, *out), F32)
+    conv = dict(kernel_spatial=kernel, stride=stride, padding=padding)
+    if inner == "taps":
+        monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    STATS.reset()
+    got = convops.pe_conv_grad(x, dy, impl="auto" if inner == "taps"
+                               else "pallas", **conv)
+    assert dict(STATS.conv_impls) == {f"s2d_{inner}": 1}
+    want = convops.pe_conv_grad(x, dy, impl="fgc", **conv)
+    assert got.shape == want.shape == (2, 6, 3) + kernel
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_strided_oracle(x, dy, kernel, stride,
+                                                    padding)),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", [
+    dict(kernel=(11, 11), stride=4, taps=144, route="s2d_taps"),
+    dict(kernel=(3, 3), stride=2, taps=16, route="s2d_taps"),
+    dict(kernel=(4, 4), stride=2, taps=16, route="s2d_taps"),
+    dict(kernel=(3, 3), stride=1, taps=9, route="taps"),
+    dict(kernel=(3, 3), stride=2, dilation=2, taps=9, route="fgc"),
+    dict(kernel=(11, 11), stride=4, impl="fgc", taps=121, route="fgc"),
+], ids=["alexnet_conv0", "odd_k", "even_k", "stride1", "dilated",
+        "alexnet_conv0_fgc"])
+def test_pe_kernel_taps_counts_the_padded_taps(tpu, case):
+    """The route of a 3-channel conv on a TPU, and the taps it computes."""
+    case = dict(case)
+    taps, kernel, route = case.pop("taps"), case.pop("kernel"), \
+        case.pop("route")
+    assert convops.pe_conv_route(kernel, 3, **case) == route
+    assert convops.route_taps(route, kernel, case.get("stride", 1)) == taps
 
 
 def test_kernel_operands_are_bf16_at_default_precision(tpu):
@@ -177,5 +268,34 @@ def test_kernel_path_matches_fgc_in_the_planned_pipeline(mode, B):
     np.testing.assert_allclose(np.asarray(n_mxu), np.asarray(n_ref),
                                rtol=2e-5)
     for a, b in zip(jax.tree.leaves(g_mxu), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("on_mesh", [False, True], ids=["no_mesh", "mesh"])
+def test_engine_runs_the_kernel_per_device_under_its_mesh(on_mesh):
+    """The SPMD partitioner cannot split a Pallas kernel, so the engine
+    traces its step under its own mesh and the kernel wrapper runs the
+    kernel on each device's examples in a ``shard_map`` over the data
+    axes; the step equals the ``fgc`` one (a one-device mesh here)."""
+    from repro.core import DPConfig, PrivacyEngine
+    from repro.launch.mesh import make_auto_mesh
+    from repro.optim import adamw_init
+    apply_fn, params, batch = _toy_cnn(8)
+    mesh = make_auto_mesh((1,), ("data",)) if on_mesh else None
+    key = jax.random.key_data(jax.random.PRNGKey(3))
+    out = {}
+    for impl in ("fgc", "pallas"):
+        eng = PrivacyEngine(
+            apply_fn, params, batch, lr=1e-2, mesh=mesh,
+            dp=DPConfig(l2_clip=0.5, noise_multiplier=1.0,
+                        norm=NormCfg(conv="pe", conv_impl=impl)))
+        out[impl] = eng.private_step(params, adamw_init(params), batch, key)
+        with eng._mesh_context():
+            jaxpr = jax.make_jaxpr(eng._step_fn())(
+                params, adamw_init(params), batch, key, {})
+        assert ("shard_map" in str(jaxpr)) == (on_mesh and impl == "pallas")
+    for a, b in zip(jax.tree.leaves(out["pallas"][0]),
+                    jax.tree.leaves(out["fgc"][0])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                    atol=1e-6)

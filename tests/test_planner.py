@@ -130,6 +130,47 @@ def test_auto_matches_naive_cnn():
     assert conv_methods == {"pe", "ghost"}
 
 
+def _alexnet_pe_plan(B, **opts):
+    cfg = get_config("alexnet").replace(img_size=67, n_classes=10)
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    batch = {"img": jax.ShapeDtypeStruct((B, 3, 67, 67), jnp.float32),
+             "label": jax.ShapeDtypeStruct((B,), jnp.int32)}
+    costmodel.clear_plan_cache()   # the route depends on the platform
+    return costmodel.get_plan(model.apply, params, batch, conv_norm="pe",
+                              **opts)
+
+
+def test_strided_conv_pe_priced_at_space_to_depth_taps(monkeypatch):
+    """On a TPU AlexNet conv0 (11x11, stride 4) forms its per-example
+    gradients through space to depth, with 12x12 taps cropped to 11x11:
+    its ``pe`` norm is priced at 144 taps, a stride-1 conv at its own 25."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    B = 4
+    plan = _alexnet_pe_plan(B)
+    conv0, conv1 = plan.layers["conv0"], plan.layers["conv1"]
+    assert conv0.norm_method == conv1.norm_method == "pe"
+    assert conv0.norm_flops == 4.0 * B * 16 * 16 * 3 * 144 * 64
+    assert conv1.norm_flops == 4.0 * B * 7 * 7 * 64 * 25 * 192
+    # the sum phase contracts the true kernel
+    assert conv0.contrib_flops == 2.0 * B * 16 * 16 * 3 * 121 * 64
+
+
+@pytest.mark.parametrize("tpu,conv_impl", [(False, "auto"), (True, "fgc")],
+                         ids=["auto_off_tpu", "explicit_fgc"])
+def test_strided_conv_pe_priced_at_the_taps_fgc_runs(monkeypatch, tpu,
+                                                     conv_impl):
+    """Where conv0's per-example gradients take ``fgc`` (off the TPU, or
+    asked for), its ``pe`` norm is priced at its own 121 taps."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "on_tpu", lambda: tpu)
+    B = 4
+    plan = _alexnet_pe_plan(B, conv_impl=conv_impl)
+    assert plan.layers["conv0"].norm_flops == 4.0 * B * 16 * 16 * 3 * 121 * 64
+
+
 def test_auto_matches_naive_lm_tied():
     cfg = get_config("llama3.2-1b").reduced()
     assert cfg.tie_embeddings

@@ -91,6 +91,42 @@ def test_pe_conv_grad_2d_compiles(one_chip, layer, dtype):
         [((B, C, S, S), dtype), ((B, D, S, S), dtype)], one_chip)
 
 
+@pytest.mark.parametrize("inner", ["taps", "pallas"])
+def test_space_to_depth_conv0_compiles(one_chip, monkeypatch, inner):
+    """AlexNet conv0 (11x11, stride 4, padding 2, 3 -> 64 at 256 px) at its
+    per-chip batch of the four-chip cell, through space to depth: 48
+    channels on a 65x65 grid, 3x3 taps, by per-tap dots or the kernel."""
+    from repro.models import convops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    B = 256
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+            for s in ((B, 3, 256, 256), (B, 64, 63, 63))]
+    compiled = jax.jit(lambda x, dy: convops._pe_conv_grad_s2d(
+        x, dy, (11, 11), 4, 2, inner)).lower(*args).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (inner == "pallas")
+
+
+def test_pe_conv_grad_compiles_on_a_data_mesh(one_chip, monkeypatch):
+    """AlexNet conv1 (5x5, 64 -> 192 on 31x31) at the four-chip cell's
+    global batch of 1024, split over a data:4 mesh of the described host:
+    the partitioner cannot split a Pallas kernel, so under the mesh the
+    wrapper runs it per device in a shard_map (``ops.per_example``)."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_auto_mesh
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = make_auto_mesh((4,), ("data",), devices=topo.devices)
+    rows = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=rows)
+            for s in ((1024, 64, 31, 31), (1024, 192, 31, 31))]
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(lambda x, dy: ops.pe_conv_grad(
+            x, dy, kernel_spatial=(5, 5), padding=2)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_pe_conv_grad_1d_compiles(one_chip):
     B, C, D, T, K = 16, 256, 256, 1026, 3
     th = pc.row_tile(T, K, 1, 1, ops.VMEM_BUDGET)
