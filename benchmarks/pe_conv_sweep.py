@@ -9,11 +9,12 @@ over the batch).
 
 Each row gives the route the call took (``tapper.STATS.conv_impls``: on a
 TPU ``auto`` takes per-tap dots or the kernel, after space to depth for
-AlexNet's strided conv0, and ``pallas`` the kernel), the median ms of
-``--iters`` calls after a warm-up, the TFLOP/s on the 2·B·T·C·K·D products
-the contraction needs (K the layer's own taps), the compiled program's
-temporary bytes, and the largest gap of its results to the first
-implementation's.  The inputs enter as (H, W, B, C)
+AlexNet's strided conv0, and ``pallas`` the kernel), the kernel's row
+tiling (``tapper.STATS.row_tiles``: rows, rows a tile, tiles), the median
+ms of ``--iters`` calls after a warm-up, the TFLOP/s on the 2·B·T·C·K·D
+products the contraction needs (K the layer's own taps), the compiled
+program's temporary bytes, and the largest gap of its results to the
+first implementation's.  The inputs enter as (H, W, B, C)
 f32 arrays, the layout the private step keeps its captures in; a call
 alone still pays the layout and type conversions that the step fuses
 into the ops making its captures (the temporary bytes show them), so a
@@ -68,6 +69,7 @@ def measure(layer: str, impl: str, batch: int, iters: int) -> dict:
     compiled = _step(impl, k, s, p).lower(xt, dyt, w).compile()
     compile_s = time.perf_counter() - t
     route = ",".join(sorted(STATS.conv_impls))
+    row_tiles = [list(k) for k in sorted(STATS.row_tiles)]
     out = compiled(xt, dyt, w)
     jax.block_until_ready(out)
     times = []
@@ -77,7 +79,8 @@ def measure(layer: str, impl: str, batch: int, iters: int) -> dict:
         times.append(time.perf_counter() - t)
     ms = statistics.median(times) * 1e3
     flops = 2.0 * batch * So * So * C * k * k * D
-    return {"layer": layer, "impl": impl, "route": route, "ms": ms,
+    return {"layer": layer, "impl": impl, "route": route,
+            "row_tiles": row_tiles, "ms": ms,
             "tflops": flops / ms / 1e9,
             "temp_mib": compiled.memory_analysis().temp_size_in_bytes / 2**20,
             "compile_s": compile_s}, out

@@ -165,8 +165,9 @@ def phase_exactness(batch: int):
 
 
 def phase_kernels():
-    """Each Pallas kernel, compiled, at VGG16 widths (batch 2) and the
-    flash kernel at T=4096, against kernels/ref.py.  The kernels run at
+    """Each Pallas kernel, compiled, at VGG16 widths (batch 2), the
+    pe_conv_grad kernel also at AlexNet conv1's (batch 256), and the flash
+    kernel at T=4096, against kernels/ref.py.  The kernels run at
     the default matmul precision, as the trainer runs them; the
     references in float32 at "highest"."""
     from repro.kernels import flash_attn, gram_norm, ops, ref
@@ -201,6 +202,13 @@ def phase_kernels():
           ops.pe_conv_grad(x, dy, kernel_spatial=(3, 3), padding=1),
           lambda: ref.pe_conv_grad_2d_ref(
               jnp.pad(x, ((0, 0), (0, 0), (1, 1), (1, 1))), dy, 3, 3))
+    # AlexNet conv1 at its cell's shape: 31 rows in two tiles, the last
+    # reaching a row past the image, whose contents VMEM leaves undefined
+    x, dy = rnd((256, 64, 31, 31)), rnd((256, 192, 31, 31))
+    check("pe_conv_grad_2d alexnet.conv1", "f32",
+          ops.pe_conv_grad(x, dy, kernel_spatial=(5, 5), padding=2),
+          lambda: ref.pe_conv_grad_2d_ref(
+              jnp.pad(x, ((0, 0), (0, 0), (2, 2), (2, 2))), dy, 5, 5))
     qkv = [rnd((1, 4096, h, 128), jnp.bfloat16) for h in (8, 2, 2)]
     f32 = [a.astype(jnp.float32) for a in qkv]
     check("flash_attention", "bf16",

@@ -67,10 +67,13 @@ class PipelineStats:
     plans), and ``conv_impls`` the implementation each per-example conv
     gradient took (``pallas`` for the MXU kernel, ``taps`` for per-tap
     dots, ``s2d_pallas`` and ``s2d_taps`` for either after space to depth,
-    ``fgc``, ``bgc``); they are not part of :meth:`snapshot`, which
+    ``fgc``, ``bgc``), and ``row_tiles`` each ``pe_conv_grad`` kernel
+    call's row tiling as ``(H, th, tiles)`` (its last tile is ragged where
+    ``tiles * th > H``); they are not part of :meth:`snapshot`, which
     covers only the whole-model pass counters."""
 
-    __slots__ = ("forwards", "backwards", "probes", "fused", "conv_impls")
+    __slots__ = ("forwards", "backwards", "probes", "fused", "conv_impls",
+                 "row_tiles")
 
     def __init__(self):
         self.reset()
@@ -81,6 +84,7 @@ class PipelineStats:
         self.probes = 0
         self.fused = 0
         self.conv_impls = collections.Counter()
+        self.row_tiles = collections.Counter()
 
     def snapshot(self) -> dict:
         return {"forwards": self.forwards, "backwards": self.backwards,

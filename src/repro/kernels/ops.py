@@ -114,9 +114,10 @@ def per_example(fn, *args):
 
 def pe_conv_grad(x, dy, *, kernel_spatial, padding=0):
     """The pe_conv_grad kernel for a convolution it takes
-    (:func:`kernel_takes`), with the row tile sized to the VMEM budget,
-    over each device's examples (:func:`per_example`); interpret mode off
-    the TPU."""
+    (:func:`kernel_takes`), with the row tile sized to the VMEM budget
+    (tallied in ``tapper.STATS.row_tiles``), over each device's examples
+    (:func:`per_example`); interpret mode off the TPU."""
+    from repro.core.tapper import STATS
     if on_tpu() and jax.config.jax_default_matmul_precision in ONE_BF16_PASS:
         # The MXU rounds f32 operands to bf16 at this precision.  Rounding
         # them where they are made lets the compiler keep bf16 copies of
@@ -127,17 +128,19 @@ def pe_conv_grad(x, dy, *, kernel_spatial, padding=0):
     p = _as_tuple(padding, rank)
     interp = not on_tpu()
     eb = _pc.examples_per_step(_pc.operand_dtype(x.dtype, dy.dtype))
+    H = x.shape[2]
     if rank == 1:
-        th = _pc.row_tile(x.shape[2], kernel_spatial[0], 1, 1,
-                          vmem_budget(), eb)
-        return per_example(functools.partial(
-            _pc.pe_conv_grad_1d, K=kernel_spatial[0], padding=p[0], th=th,
-            interpret=interp), x, dy)
-    KH, KW = kernel_spatial
-    th = _pc.row_tile(x.shape[2], KH, KW, x.shape[3], vmem_budget(), eb)
-    return per_example(functools.partial(
-        _pc.pe_conv_grad_2d, KH=KH, KW=KW, padding=p, th=th,
-        interpret=interp), x, dy)
+        K = kernel_spatial[0]
+        th = _pc.row_tile(H, K, 1, 1, vmem_budget(), eb)
+        fn = functools.partial(_pc.pe_conv_grad_1d, K=K, padding=p[0], th=th,
+                               interpret=interp)
+    else:
+        KH, KW = kernel_spatial
+        th = _pc.row_tile(H, KH, KW, x.shape[3], vmem_budget(), eb)
+        fn = functools.partial(_pc.pe_conv_grad_2d, KH=KH, KW=KW, padding=p,
+                               th=th, interpret=interp)
+    STATS.row_tiles[H, th, -(-H // th)] += 1
+    return per_example(fn, x, dy)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,

@@ -10,7 +10,9 @@ operands where they lie.  On the TPU a convolution's activations and
 cotangents are kept with the image's rows and columns outermost and
 (examples, channels) as the tiled minor pair, i.e. as (H, W, B, C)
 arrays; the kernel takes them in that layout, so the transposes in front
-of it are layout changes and no copy of a capture is made.  Each grid
+of it are layout changes and no copy of a capture is made.  The row
+tiles need not divide H: the last one may reach past the image, and its
+rows off the image are zeroed in VMEM, like the columns past it.  Each grid
 step holds ``th`` rows of x and the th+KH-1 rows of δy they meet, for
 one tile's examples (8 in f32, 16 in bf16), flattened to positions
 p = row·Wx + col with Wx = W+KW-1 columns, the ones past the image
@@ -78,12 +80,13 @@ def vmem_bytes(th: int, KH: int, KW: int, W: int, eb: int = WORDS) -> int:
 
 def row_tile(H: int, KH: int, KW: int, W: int, budget: int,
              eb: int = WORDS) -> int:
-    """Input rows per grid step: the largest divisor of ``H`` whose
-    working set fits ``budget`` (1 when none does)."""
-    for th in range(H, 0, -1):
-        if H % th == 0 and vmem_bytes(th, KH, KW, W, eb) <= budget:
-            return th
-    return 1
+    """Input rows per grid step: the fewest row tiles whose working set
+    fits ``budget``, balanced, so the last tile reaches fewer rows past
+    ``H`` than there are tiles (1 when no tile fits)."""
+    fit = next((th for th in range(H, 0, -1)
+                if vmem_bytes(th, KH, KW, W, eb) <= budget), 1)
+    tiles = -(-H // fit)
+    return -(-H // tiles)
 
 
 def _examples(ref2, pos, n, k, packed):
@@ -101,10 +104,11 @@ def _examples(ref2, pos, n, k, packed):
             for w in (lax.shift_left(u, np.uint32(16)), hi)]
 
 
-def _kernel(x_ref, dy_ref, o_ref, w_ref, *, KH, KW, P, Q, H, W, Ho):
+def _kernel(x_ref, dy_ref, o_ref, w_ref, *, KH, KW, P, Q, H, W, Ho, top):
     th, Wx = x_ref.shape[:2]
     R, Wo = dy_ref.shape[:2]
     U = KH - 1 - P
+    nt = -(-H // th)
     r = pl.program_id(3)
 
     @pl.when(r == 0)
@@ -113,14 +117,20 @@ def _kernel(x_ref, dy_ref, o_ref, w_ref, *, KH, KW, P, Q, H, W, Ho):
 
     if Wx > W:
         x_ref[:, W:] = jnp.zeros((th, Wx - W) + x_ref.shape[2:], x_ref.dtype)
+    if nt * th > H:
+        @pl.when(r == nt - 1)
+        def _rows():    # the last tile's rows past the image
+            x_ref[H - (nt - 1) * th:] = jnp.zeros(
+                (nt * th - H,) + x_ref.shape[1:], x_ref.dtype)
+
     # The window's row m holds output row r*th - U + m and lies at scratch
     # row 1 + U + m; the block holds output rows from its clamped start.
-    start = jnp.clip(r * th - U, 0, max(Ho - R, 0))
+    start = jnp.clip(r * th - U, 0, top)
     if Wx > Wo:
         w_ref[:, Wo:] = jnp.zeros((w_ref.shape[0], Wx - Wo) + w_ref.shape[2:],
                                   w_ref.dtype)
     w_ref[pl.ds(1 + 2 * U + start - r * th, R), :Wo] = dy_ref[...]
-    for t in range(H // th):    # zero the window's rows off the image
+    for t in range(nt):     # zero the window's rows off the image
         lo = min(max(U - t * th, 0), R)
         hi = max(min(Ho + U - t * th, R), lo)
         if (lo, hi) == (0, R):
@@ -166,7 +176,8 @@ def pe_conv_grad_2d(x, dy, *, KH: int, KW: int, padding=(0, 0), th: int = 0,
     """x (B,C,H,W), dy (B,D,H',W') -> (B,D,C,KH,KW) fp32, for a stride-1,
     undilated, ungrouped convolution with ``padding`` (P, Q) rows and
     columns on each side, 0 <= P < KH and 0 <= Q < KW.  ``th`` input rows
-    (a divisor of H) per grid step, all of them when 0.  bf16 operands
+    per grid step, all of them when 0; the last of the ceil(H/th) row
+    tiles may reach past H, and no operand is padded in HBM.  bf16 operands
     (both) are multiplied as bf16 on the MXU, anything else as f32; the
     sums are f32."""
     B, C, H, W = x.shape
@@ -175,8 +186,12 @@ def pe_conv_grad_2d(x, dy, *, KH: int, KW: int, padding=(0, 0), th: int = 0,
     assert 0 <= P < KH and 0 <= Q < KW, (padding, KH, KW)
     assert (Ho, Wo) == (H + 2 * P - KH + 1, W + 2 * Q - KW + 1)
     th = th or H
-    assert H % th == 0, (H, th)
+    nt = -(-H // th)
     U, R, Wx = KH - 1 - P, th + KH - 1, W + KW - 1
+    # Where the last tile's δy block starts: on the image's last R rows,
+    # unless the scratch, whose first row is output row r*th - 2U - 1,
+    # begins below them (a ragged last tile); then there, past the image.
+    top = max(Ho - R, (nt - 1) * th - 2 * U - 1, 0)
     dtype = operand_dtype(x.dtype, dy.dtype)
     eb = examples_per_step(dtype)
     nb, nd, nc = -(-B // eb), -(-D // LANES), -(-C // LANES)
@@ -185,16 +200,17 @@ def pe_conv_grad_2d(x, dy, *, KH: int, KW: int, padding=(0, 0), th: int = 0,
     xt = x.astype(dtype).transpose(2, 3, 0, 1)
     dyt = dy.astype(dtype).transpose(2, 3, 0, 1)
     out = pl.pallas_call(
-        functools.partial(_kernel, KH=KH, KW=KW, P=P, Q=Q, H=H, W=W, Ho=Ho),
-        grid=(nb, nd, nc, H // th),
+        functools.partial(_kernel, KH=KH, KW=KW, P=P, Q=Q, H=H, W=W, Ho=Ho,
+                          top=top),
+        grid=(nb, nd, nc, nt),
         in_specs=[
-            pl.BlockSpec((E(th), E(Wx, (0, Wx - W)), b_blk,
+            pl.BlockSpec((E(th, (0, nt * th - H)), E(Wx, (0, Wx - W)), b_blk,
                           E(LANES, (0, nc * LANES - C))),
                          lambda e, d, c, r: (r * th, 0, e * eb, c * LANES)),
-            pl.BlockSpec((E(R, (0, max(R - Ho, 0))), E(Wo), b_blk,
+            pl.BlockSpec((E(R, (0, top + R - Ho)), E(Wo), b_blk,
                           E(LANES, (0, nd * LANES - D))),
                          lambda e, d, c, r: (
-                             jnp.clip(r * th - U, 0, max(Ho - R, 0)), 0,
+                             jnp.clip(r * th - U, 0, top), 0,
                              e * eb, d * LANES)),
         ],
         out_specs=pl.BlockSpec((eb, KH * KW, min(C, LANES), min(D, LANES)),

@@ -86,7 +86,13 @@ def test_pe_conv_grad_2d_kernel(shape):
     (3, 5, 4, 9, 6, 5, 2, 3),       # 5x5, padding 2
     (2, 3, 6, 6, 5, 3, 1, 1),       # one row a step
     (3, 4, 5, 8, 8, 3, 0, 2),       # no padding
-], ids=["lanes", "k5", "th1", "valid"])
+    # row tiles that do not divide H: the last tile reaches past the image
+    (3, 5, 4, 11, 11, 5, 2, 6),     # 5x5 as AlexNet's conv1, one row over
+    (2, 3, 4, 7, 6, 3, 1, 3),       # 3x3, two rows over
+    (2, 3, 4, 9, 5, 3, 1, 4),       # the last tile holds one real row
+    (2, 3, 4, 10, 6, 3, 0, 4),      # the last δy window past the image
+], ids=["lanes", "k5", "th1", "valid", "ragged_k5", "ragged_k3",
+        "ragged_one_row", "ragged_dy_past"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_pe_conv_grad_2d_kernel_tiles(case, dtype):
     """Row tiles, padding, lane tiles and partial blocks against the

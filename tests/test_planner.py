@@ -275,22 +275,47 @@ def test_dense_norm_and_contrib_methods():
 
 
 def test_pe_conv_bd_autotune():
-    """The kernel's tile: output rows per grid step, sized to the VMEM
-    budget (its channel tiles are fixed at 128 lanes)."""
+    """The kernel's tile: input rows per grid step, the fewest row tiles
+    that fit the VMEM budget, balanced (its channel tiles are fixed at 128
+    lanes); the last tile may reach past the image."""
     from repro.kernels import pe_conv_grad as pc
-    for eb in (8, 16):
-        th = pc.row_tile(30, 3, 3, 32, kops.VMEM_BUDGET, eb)
-        # a divisor of the output rows whose working set fits the budget
-        assert 30 % th == 0
-        assert pc.vmem_bytes(th, 3, 3, 32, eb) <= kops.VMEM_BUDGET
-        # a smaller budget never gives a larger tile
-        small = pc.row_tile(30, 3, 3, 32, 16 << 20, eb)
-        assert 30 % small == 0 and small <= th
-    # VGG16's conv1 at 256 px needs several row tiles; its conv8 does not
-    assert pc.row_tile(256, 3, 3, 256, kops.VMEM_BUDGET, 16) < 256
-    assert pc.row_tile(32, 3, 3, 32, kops.VMEM_BUDGET, 16) == 32
+    budget = kops.VMEM_BUDGET
+    for H, K, W in ((30, 3, 32), (31, 5, 31), (256, 3, 256), (64, 3, 64)):
+        for eb in (8, 16):
+            th = pc.row_tile(H, K, K, W, budget, eb)
+            tiles = -(-H // th)
+            # the tile fits the budget
+            assert pc.vmem_bytes(th, K, K, W, eb) <= budget
+            # no smaller tile count fits: each tile of fewer is too large
+            if tiles > 1:
+                assert pc.vmem_bytes(-(-H // (tiles - 1)), K, K, W,
+                                     eb) > budget
+            # balanced: the last tile reaches fewer rows past H than
+            # there are tiles
+            assert 0 <= tiles * th - H < tiles
+            # a smaller budget never gives a larger tile
+            assert pc.row_tile(H, K, K, W, 16 << 20, eb) <= th
+    # AlexNet conv1 (31 rows, prime) in two tiles, one row past the image
+    assert pc.row_tile(31, 5, 5, 31, budget, 16) == 16
+    # VGG16 at 256 px: conv1 in 86 tiles of 3, conv2-9 as before
+    assert pc.row_tile(256, 3, 3, 256, budget, 16) == 3
+    assert pc.row_tile(128, 3, 3, 128, budget, 16) == 8
+    assert pc.row_tile(64, 3, 3, 64, budget, 16) == 16
+    assert pc.row_tile(32, 3, 3, 32, budget, 16) == 32
     # a budget nothing fits falls back to one row per step
     assert pc.row_tile(30, 3, 3, 32, 1 << 10) == 1
+
+
+def test_pe_conv_row_tiles_tallied():
+    """Tracing an AlexNet-conv1-shaped call (5x5, padding 2, 31x31)
+    tallies its row tiling: two tiles of 16 rows, the last one row past
+    the image."""
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16)
+            for s in ((16, 64, 31, 31), (16, 192, 31, 31))]
+    STATS.reset()
+    jax.eval_shape(lambda x, dy: kops.pe_conv_grad(
+        x, dy, kernel_spatial=(5, 5), padding=2), *args)
+    assert STATS.row_tiles == {(31, 16, 2): 1}
 
 
 def test_planner_backward_sum_phase_reachable():

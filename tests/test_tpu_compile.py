@@ -91,6 +91,19 @@ def test_pe_conv_grad_2d_compiles(one_chip, layer, dtype):
         [((B, C, S, S), dtype), ((B, D, S, S), dtype)], one_chip)
 
 
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_pe_conv_grad_2d_compiles_alexnet_conv1(one_chip, dtype):
+    """AlexNet conv1 (5x5, padding 2, 64 -> 192 on 31x31) at the batch of
+    the alexnet.flat.b256 cell, with the row tile the wrapper picks: 31
+    rows in two tiles of 16, the last one row past the image."""
+    th = pc.row_tile(31, 5, 5, 31, ops.VMEM_BUDGET,
+                     pc.examples_per_step(dtype))
+    assert 31 % th
+    _compile(lambda x, dy: pc.pe_conv_grad_2d(
+        x, dy, KH=5, KW=5, padding=(2, 2), th=th, interpret=False),
+        [((256, 64, 31, 31), dtype), ((256, 192, 31, 31), dtype)], one_chip)
+
+
 @pytest.mark.parametrize("inner", ["taps", "pallas"])
 def test_space_to_depth_conv0_compiles(one_chip, monkeypatch, inner):
     """AlexNet conv0 (11x11, stride 4, padding 2, 3 -> 64 at 256 px) at its
