@@ -215,7 +215,7 @@ def run_attn(out_path: str = "BENCH_strategies.json") -> dict:
         def f(p, b):
             _, gsum, _ = clipped_grad_sum(model.apply, p, b, l2_clip=1.0,
                                           strategy="ghost",
-                                          attn_norm=method)
+                                          overrides={"*attn": method})
             return gsum
         return jax.jit(f)
 

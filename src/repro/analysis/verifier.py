@@ -231,7 +231,8 @@ def verify_engine(engine, *, opt=None,
         f"{len(plan.groups)} group realizations present in the graph, "
         f"STATS census {stats_delta}, fingerprint {plan.fingerprint or '-'}"
         if plan is not None
-        else f"fixed strategy {engine.dp.strategy!r}: no plan to check")
+        else f"materializing strategy {engine.dp.strategy!r}: no plan to "
+        f"check")
 
     owner = getattr(engine.apply_fn, "__self__", None)
     model = (type(owner).__qualname__ if owner is not None

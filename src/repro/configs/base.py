@@ -70,7 +70,6 @@ class ModelConfig:
     remat: bool = False
     fsdp: bool = False
     vocab_pad_to: int = 128
-    dp_strategy: str = "ghost"
     moe_lb_coef: float = 0.01
     # long-context applicability: full-attention archs skip long_500k
     subquadratic: bool = False

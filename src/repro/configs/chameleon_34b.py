@@ -9,5 +9,5 @@ CONFIG = ModelConfig(
     name="chameleon-34b", family="vlm", n_layers=48, d_model=8192,
     n_heads=64, n_kv=8, d_ff=22016, vocab=65536, head_dim=128,
     norm="layernorm", mlp="swiglu", qk_norm=True, rope_theta=1e4,
-    dtype="bfloat16", remat=True, fsdp=True, dp_strategy="bk",
+    dtype="bfloat16", remat=True, fsdp=True,
     prefill_last_only=True)

@@ -15,4 +15,4 @@ CONFIG = ModelConfig(
     capacity_factor=2.0, mla=True, q_lora_rank=1536, kv_lora_rank=512,
     qk_rope_dim=64, qk_nope_dim=128, v_head_dim=128, rope_theta=1e4,
     dtype="bfloat16", remat=True, fsdp=True, moe_impl="gather",
-    dp_strategy="ghost", prefill_last_only=True)
+    prefill_last_only=True)

@@ -5,4 +5,4 @@ CONFIG = ModelConfig(
     name="llama3.2-1b", family="dense", n_layers=16, d_model=2048,
     n_heads=32, n_kv=8, d_ff=8192, vocab=128256, head_dim=64,
     norm="rmsnorm", mlp="swiglu", tie_embeddings=True, rope_theta=5e5,
-    dtype="bfloat16", remat=False, dp_strategy="bk", prefill_last_only=True)
+    dtype="bfloat16", remat=False, prefill_last_only=True)

@@ -11,4 +11,4 @@ CONFIG = ModelConfig(
     n_enc_layers=12, n_dec_layers=12, d_model=1024, n_heads=16, n_kv=16,
     d_ff=8192, vocab=256206, head_dim=64, norm="layernorm", mlp="gelu",
     rope_theta=1e4, frontend="frames", dtype="bfloat16", remat=True,
-    dp_strategy="bk", prefill_last_only=True)
+    prefill_last_only=True)

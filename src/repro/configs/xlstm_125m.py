@@ -10,4 +10,4 @@ CONFIG = ModelConfig(
     name="xlstm-125m", family="ssm", n_layers=12, d_model=768, n_heads=4,
     n_kv=4, d_ff=0, vocab=50304, norm="rmsnorm", slstm_every=4,
     ssm_expand=2, ssm_conv=4, dtype="bfloat16", subquadratic=True,
-    dp_strategy="bk", prefill_last_only=True)
+    prefill_last_only=True)

@@ -12,4 +12,4 @@ CONFIG = ModelConfig(
     n_heads=32, n_kv=32, d_ff=10240, vocab=32000, head_dim=80,
     norm="rmsnorm", mlp="swiglu", ssm_state=64, ssm_expand=2, ssm_conv=4,
     attn_every=6, window=4096, rope_theta=1e4, dtype="bfloat16", remat=True,
-    subquadratic=True, dp_strategy="bk", prefill_last_only=True)
+    subquadratic=True, prefill_last_only=True)

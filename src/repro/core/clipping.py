@@ -273,13 +273,19 @@ class DPConfig:
     def conv_norm(self) -> str:
         return self.norm.conv
 
+    @property
+    def planned(self) -> bool:
+        """Whether the strategy executes an ExecPlan (ghost, bk, auto)
+        rather than materializing every per-example gradient."""
+        return self.strategy in costmodel.PLANNED_STRATEGIES
+
     def planner_opts(self) -> dict:
         """Keyword arguments for :func:`repro.core.costmodel.get_plan`."""
         return dict(norm_method=self.norm.dense, embed_method=self.norm.embed,
                     conv_norm=self.norm.conv, mem_budget=self.norm.mem_budget,
                     conv_impl=self.norm.conv_impl, overrides=self.overrides,
                     clip_mode=self.clipping.mode,
-                    clip_fused=self.clipping.fused)
+                    clip_fused=self.clipping.fused, strategy=self.strategy)
 
 
 def add_noise(grad_sum, key, noise_multiplier: float, l2_clip: float):
@@ -308,13 +314,13 @@ def resolve_microbatches(apply_fn, params, batch, cfg: DPConfig,
                          plan=None, mesh=None) -> int:
     """Resolve ``cfg.microbatches`` to a concrete count.  ``"auto"`` derives
     it from the full-batch ExecPlan's memory estimates (planned strategies
-    only; fixed strategies have no plan to consult and run unsplit).
+    only; the materializing ones have no plan to consult and run unsplit).
     ``mesh`` makes the consulted plan's estimates per-device, so the split
     is sized for a device's batch shard rather than the global batch."""
     m = cfg.microbatches
     if m != "auto":
         return int(m)
-    if cfg.strategy != "auto":
+    if not cfg.planned:
         return 1
     if plan is None:
         plan = costmodel.get_plan(apply_fn, params, batch,

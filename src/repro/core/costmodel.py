@@ -29,6 +29,12 @@ shapes (from a single shape-only probe), it chooses
                      input-cotangent chain), amortized over all such
                      groups.
 
+The strategy the plan executes is one of :data:`PLANNED_STRATEGIES`:
+``auto`` makes every choice above; ``bk`` stashes nothing and contracts
+every group; ``ghost`` stashes nothing and takes every group's sum from
+the one weighted backward.  Under ``ghost`` and ``bk`` a layer keeps the
+norm the caller fixed, else the best norm that stashes nothing.
+
 Plans are cached on (model identity, batch/param shapes, knobs): steady
 state training re-plans nothing and never re-probes — see
 :func:`get_plan`.  Defaults target TPU v5e; the memory budget guards HBM
@@ -78,6 +84,9 @@ from repro.core.tapper import LayerMeta, get_subtree, probe
 from repro.launch.mesh import DATA_AXIS_NAMES
 
 GRAM_CHUNK = 1024
+# The strategies that execute an ExecPlan; naive, multi and crb
+# materialize every per-example gradient instead (repro.core.strategies).
+PLANNED_STRATEGIES = ("ghost", "bk", "auto")
 STREAM_MEM_BUDGET = 2 << 30  # bytes of per-example-grad scratch we tolerate
 BYTES = 4
 # A weighted second backward costs ~2x the forward on top of the wgrad
@@ -319,26 +328,6 @@ def seg_norm_method(S: int, Di: int, Do: int, B: int, G: int,
     if stream_flops < gram_flops and stream_mem <= mem_budget:
         return "stream"
     return "gram"
-
-
-def conv_norm_method(T: int, C: int, D: int, K: int, B: int, groups: int = 1,
-                     mem_budget: int = STREAM_MEM_BUDGET) -> str:
-    """Conv ghost-norm (im2col Gram over T output positions with per-group
-    features F = (C/g)·K) vs materializing the per-example weight gradient
-    (the paper's Algorithm 2).  Early layers (large spatial T, few
-    channels) want ``pe``; late layers (tiny T, wide channels) want
-    ``ghost`` — the per-layer mix of Bu et al. (2022).
-
-    ``T`` = output positions, ``K`` = prod(kernel spatial dims).
-    """
-    g = max(groups, 1)
-    F, Dg = (C // g) * K, D // g
-    ghost_flops = 2 * T * T * (F + Dg) * g
-    pe_flops = 4 * T * F * Dg * g
-    pe_mem = B * D * (C // g) * K * BYTES
-    if pe_flops < ghost_flops and pe_mem <= mem_budget:
-        return "pe"
-    return "ghost"
 
 
 EMBED_PE_BUDGET = 32 << 20  # materialize embed pe grads below this
@@ -1012,8 +1001,10 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
                    mem_budget: int = STREAM_MEM_BUDGET,
                    overrides=None, mesh=None, clip_mode: str = "flat",
                    clip_fused: bool = True, calibration=None,
-                   conv_impl: str = "auto") -> ExecPlan:
-    """Build the per-layer plan from probed shapes.
+                   conv_impl: str = "auto",
+                   strategy: str = "auto") -> ExecPlan:
+    """Build the per-layer plan from probed shapes, for one of
+    :data:`PLANNED_STRATEGIES`.
 
     Fixed ``norm_method`` / ``embed_method`` / ``conv_norm`` override the
     analytic choice uniformly (the planner still fills in cost estimates);
@@ -1035,6 +1026,10 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
     price below goes through the resolved :class:`CostConstants`, with
     :data:`ANALYTIC_CONSTANTS` as the documented fallback.
     """
+    if strategy not in PLANNED_STRATEGIES:
+        raise ValueError(
+            f"strategy {strategy!r} has no plan; planned strategies are "
+            f"{PLANNED_STRATEGIES}")
     overrides = normalize_overrides(overrides)
     ms = mesh_axes(mesh)
     d = mesh_data_size(ms)
@@ -1049,13 +1044,23 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
             except (KeyError, TypeError):
                 psub = None
         ov = _override_for(name, meta.kind, overrides)
-        layers[name] = _plan_layer(
+        lp = _plan_layer(
             name, meta, cap_shapes[name], tap_shapes[name],
             norm_method=ov or norm_method, embed_method=ov or embed_method,
             conv_norm=ov or conv_norm, mem_budget=mem_budget,
             vocab=_vocab_of(meta, params) if meta.kind == "embed" else None,
             params_sub=psub, mesh=ms, clip_mode=clip_mode,
             clip_fused=clip_fused, conv_impl=conv_impl, cc=cc)
+        if strategy != "auto" and lp.stash:
+            # ghost and bk stash nothing: the layer keeps the norm the
+            # caller asked for, else takes its best norm without a stash.
+            asked = ov or {"embed": embed_method,
+                           "conv": conv_norm}.get(meta.kind, norm_method)
+            lp = dataclasses.replace(
+                lp, stash=False,
+                norm_method=(lp.norm_method if asked == lp.norm_method
+                             else lp.fallback_norm or lp.norm_method))
+        layers[name] = lp
         by_path.setdefault(meta.path, []).append(name)
 
     total_wgrad = sum(lp.wgrad_flops for lp in layers.values())
@@ -1088,8 +1093,11 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
                     # beats segsum + Gram + the cross term, and stashes.
                     mode = "group_pe"
             # group_pe stashes the summed per-example grad during the norm
-            # phase; tied contracts per member.
-            sum_method = "stash" if mode == "group_pe" else "contrib"
+            # phase (bk contracts it per member); tied contracts per member.
+            sum_method = ("stash" if mode == "group_pe" and strategy == "auto"
+                          else "contrib")
+        if strategy == "ghost":
+            sum_method = "backward"
         groups.append(GroupPlan(path, tuple(names), mode, sum_method))
 
     # All stashes live together from the norm phase to the sum phase, so
@@ -1121,7 +1129,7 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
     # coefficients make every contraction direct (no phase barrier to
     # amortize a backward against).
     candidates: list[tuple[float, int]] = []
-    if clip_mode == "flat":
+    if clip_mode == "flat" and strategy == "auto":
         for i, g in enumerate(groups):
             if g.sum_method != "contrib":
                 continue
@@ -1131,7 +1139,7 @@ def plan_execution(metas: dict, cap_shapes: dict, tap_shapes: dict,
                 candidates.append((cost_c, i))
 
     saving = sum(s for s, _ in candidates)
-    needs_backward = saving > backward_cost
+    needs_backward = strategy == "ghost" or saving > backward_cost
     if needs_backward:
         for _, gi in candidates:
             groups[gi] = dataclasses.replace(groups[gi],
@@ -1405,13 +1413,13 @@ def check_plan_matches(plan: ExecPlan, *, fingerprint: str | None = None,
 
 def _opts_tuple(norm_method, embed_method, conv_norm, mem_budget,
                 overrides, mesh, clip_mode="flat", clip_fused=True,
-                calibration=None, conv_impl="auto") -> tuple:
+                calibration=None, conv_impl="auto", strategy="auto") -> tuple:
     ms = mesh_axes(mesh)
     calib = _resolve_calibration(calibration, ms)
     return (norm_method, embed_method, conv_norm, mem_budget,
             normalize_overrides(overrides), ms,
             (str(clip_mode), bool(clip_fused)),
-            "" if calib is None else calib.digest(), conv_impl)
+            "" if calib is None else calib.digest(), conv_impl, strategy)
 
 
 def plan_fingerprint(apply_fn, params, batch, *, norm_method: str = "auto",
@@ -1419,14 +1427,14 @@ def plan_fingerprint(apply_fn, params, batch, *, norm_method: str = "auto",
                      mem_budget: int = STREAM_MEM_BUDGET,
                      overrides=None, mesh=None, clip_mode: str = "flat",
                      clip_fused: bool = True, calibration=None,
-                     conv_impl: str = "auto") -> str:
+                     conv_impl: str = "auto", strategy: str = "auto") -> str:
     """The fingerprint :func:`get_plan` would key this request on — same
     knob normalization, no probe."""
     return model_fingerprint(
         apply_fn, params, batch,
         _opts_tuple(norm_method, embed_method, conv_norm, mem_budget,
                     overrides, mesh, clip_mode, clip_fused, calibration,
-                    conv_impl))
+                    conv_impl, strategy))
 
 
 def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
@@ -1434,7 +1442,7 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
              mem_budget: int = STREAM_MEM_BUDGET,
              overrides=None, mesh=None, clip_mode: str = "flat",
              clip_fused: bool = True, calibration=None,
-             conv_impl: str = "auto") -> ExecPlan:
+             conv_impl: str = "auto", strategy: str = "auto") -> ExecPlan:
     """Cached planner entry point.  The anchor reference pinned in the
     cached plan keeps ``id(apply_fn.__self__)`` stable for the entry's
     lifetime, so a recycled id can never alias a different model.  A
@@ -1449,7 +1457,7 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
     code."""
     opts = _opts_tuple(norm_method, embed_method, conv_norm, mem_budget,
                        overrides, mesh, clip_mode, clip_fused, calibration,
-                       conv_impl)
+                       conv_impl, strategy)
     ov, ms = opts[4], opts[5]
     key = plan_cache_key(apply_fn, params, batch, opts)
     plan = _PLAN_CACHE.get(key)
@@ -1470,7 +1478,7 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
             cand_opts = _opts_tuple(
                 norm_method, embed_method, conv_norm, mem_budget,
                 overrides, tuple(cand.mesh), clip_mode, clip_fused,
-                calibration, conv_impl)
+                calibration, conv_impl, strategy)
             if cand.fingerprint == model_fingerprint(apply_fn, params,
                                                      batch, cand_opts):
                 check_plan_matches(cand, mesh=ms)
@@ -1481,7 +1489,7 @@ def get_plan(apply_fn, params, batch, *, norm_method: str = "auto",
             norm_method=norm_method, embed_method=embed_method,
             conv_norm=conv_norm, mem_budget=mem_budget, overrides=ov,
             mesh=ms, clip_mode=clip_mode, clip_fused=clip_fused,
-            calibration=calibration, conv_impl=conv_impl)
+            calibration=calibration, conv_impl=conv_impl, strategy=strategy)
         plan = dataclasses.replace(plan, fingerprint=fp, batch_sig=sig)
     object.__setattr__(plan, "_anchor", getattr(apply_fn, "__self__",
                                                 apply_fn))

@@ -21,8 +21,12 @@ first-class value —
   * ``engine.noisy_grad()``    the eager/jit-composable gradient-only
                                path (what ``private_step`` jits).
 
-Steady state executes exactly one forward and one backward per step for
-``strategy="auto"`` (counters in :data:`repro.core.tapper.STATS`).
+The strategies ``ghost``, ``bk`` and ``auto`` each execute a plan.  Steady
+state executes exactly one forward and one backward per step, plus one of
+each for a plan that takes the shared weighted backward (always under
+``ghost``; counters in :data:`repro.core.tapper.STATS`).  ``naive``,
+``multi`` and ``crb`` materialize every per-example gradient and have no
+plan.
 
 Sharded execution: pass ``mesh=`` a ``jax.sharding.Mesh`` and the plan
 is built mesh-aware (per-device memory, collective-bytes cost terms, the
@@ -220,7 +224,7 @@ class PrivacyEngine:
         self.replan_events: list[ReplanEvent] = []
         self._step_ema: float | None = None
         self._step_obs = 0
-        if plan is not None and self.dp.strategy == "auto":
+        if plan is not None and self.dp.planned:
             # Fail loudly *now* on a stale injected plan, naming the
             # offending field (mesh / batch / clip mode / calibration /
             # fingerprint).
@@ -339,7 +343,7 @@ class PrivacyEngine:
         Inert without a calibration or with ``mispredict_threshold=None``
         — the analytic constants carry no time unit worth trusting."""
         if (self.mispredict_threshold is None or self._calibration is None
-                or self.dp.strategy != "auto"):
+                or not self.dp.planned):
             return None
         seconds = float(seconds)
         self._step_obs += 1
@@ -425,10 +429,9 @@ class PrivacyEngine:
                   + (f" mesh={costmodel.format_mesh(self._mesh_axes)}"
                      if self._mesh_axes else ""))
         cal = self._explain_calibration()
-        if self.dp.strategy != "auto":
-            return (header + f"\nfixed strategy {self.dp.strategy!r}: the "
-                    "planner is bypassed; plan below is advisory.\n"
-                    + cal + "\n" + self.plan().explain())
+        if not self.dp.planned:
+            return (header + f"\nmaterializing strategy {self.dp.strategy!r}:"
+                    " no plan.\n" + cal)
         return header + "\n" + cal + "\n" + self.plan().explain()
 
     def save_plan(self, path: str):
@@ -448,7 +451,7 @@ class PrivacyEngine:
         """The resolved microbatch count (plan-driven for ``"auto"``) —
         the same resolution rule legacy ``dp_gradient`` applies."""
         plan = self._plan
-        if self.dp.microbatches == "auto" and self.dp.strategy == "auto":
+        if self.dp.microbatches == "auto" and self.dp.planned:
             plan = self.plan()
         return resolve_microbatches(self.apply_fn, self._params_spec,
                                     self._batch_spec, self.dp, plan=plan,
@@ -457,7 +460,7 @@ class PrivacyEngine:
     def _exec_plan(self) -> costmodel.ExecPlan | None:
         """The plan matching the shapes the step actually executes: the
         full-batch plan, or a per-microbatch-shape plan when splitting."""
-        if self.dp.strategy != "auto":
+        if not self.dp.planned:
             return None
         m = self.microbatches()
         if m == 1:

@@ -35,7 +35,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.analysis.markers import tag
 from repro.core import costmodel
@@ -100,6 +99,8 @@ def dense_norm_sq(meta: LayerMeta, cap, dy, method: str = "auto"):
     B, T, Di = x.shape
     Do = g.shape[-1]
     if method == "auto":
+        # Only the attention block's inner taps pass "auto" this far; the
+        # plan names every other layer's method.
         method = costmodel.dense_norm_method(T, Di, Do, B)
     if method == "rank1" and T != 1:
         method = "gram"
@@ -214,12 +215,8 @@ def seg_dense_pe_grad(meta: LayerMeta, cap, dy):
     return out
 
 
-def seg_dense_norm_sq(meta: LayerMeta, cap, dy, method: str = "auto"):
+def seg_dense_norm_sq(meta: LayerMeta, cap, dy, method: str = "gram"):
     x, g, seg, B = _seg_flatten(meta, cap, dy)
-    G, S, Di = x.shape
-    Do = g.shape[-1]
-    if method == "auto":
-        method = costmodel.seg_norm_method(S, Di, Do, B, G)
     # Both methods scan over the group (expert) axis so peak extra memory
     # is one group's worth: (B,Di,Do) for stream, (S,S) for gram.
     if method == "stream":
@@ -289,8 +286,6 @@ def embed_norm_sq(meta: LayerMeta, cap, dy, method: str = "segsum",
     ids2 = ids.reshape(B, -1)
     T = ids2.shape[1]
     g2 = g.reshape(B, T, -1)
-    if method == "auto":
-        method = costmodel.embed_norm_method(T, g2.shape[-1], B, vocab)
     if method == "pe":
         return _realized(_sumsq(embed_pe_grad(meta, cap, dy, vocab)),
                          meta, "pe")
@@ -417,13 +412,6 @@ def conv_norm_sq_ghost(meta: LayerMeta, cap, dy, *, use_pallas: bool = False):
 
 def conv_norm_sq(meta: LayerMeta, cap, dy, impl: str = "auto",
                  method: str = "pe"):
-    if method == "auto":
-        st = meta.static
-        T = int(np.prod(dy.shape[2:]))
-        K = int(np.prod(st["kernel_shape"][2:]))
-        method = costmodel.conv_norm_method(
-            T, cap["x"].shape[1], dy.shape[1], K, dy.shape[0],
-            max(st.get("groups", 1), 1))
     if method in ("ghost", "pallas"):
         return _realized(conv_norm_sq_ghost(
             meta, cap, dy, use_pallas=(method == "pallas")), meta, method)
@@ -563,9 +551,8 @@ def attn_pe_grad(meta: LayerMeta, cap, dy, params_sub):
     return out
 
 
-def attn_norm_sq(meta: LayerMeta, cap, dy, params_sub, method: str = "auto"):
-    if method == "auto":
-        method = "ghost"
+def attn_norm_sq(meta: LayerMeta, cap, dy, params_sub,
+                 method: str = "ghost"):
     if method == "pe":
         return _realized(_sumsq(attn_pe_grad(meta, cap, dy, params_sub)),
                          meta, "pe")
@@ -653,7 +640,7 @@ def _fold_into_seq(meta: LayerMeta, cap, dy):
 def apply_kind(op: str, meta: LayerMeta, cap, dy, *, params_sub=None,
                weights=None, norm_method: str = "auto",
                conv_impl: str = "auto", embed_method: str = "segsum",
-               conv_norm: str = "pe", attn_norm: str = "auto"):
+               conv_norm: str = "pe", attn_norm: str = "ghost"):
     """Dispatch `op` in {"pe_grad","norm_sq","contrib"} over any kind,
     handling stacked (scanned) axes and shared parameters."""
     kind = meta.kind
@@ -745,7 +732,7 @@ def apply_norm_contrib(meta: LayerMeta, cap, dy, *, weights,
                        params_sub=None, fused: bool = True,
                        conv_impl: str = "auto", norm_method: str = "auto",
                        embed_method: str = "segsum",
-                       conv_norm: str = "auto", attn_norm: str = "auto"):
+                       conv_norm: str = "pe", attn_norm: str = "ghost"):
     """Per-example squared norms *and* the weighted sum Σ_b w_b·g_b from
     one pass over the captures.  Valid whenever the weights are known
     entering the pass (stale-coefficient clipping).
@@ -794,7 +781,7 @@ def _unscanned(meta: LayerMeta) -> LayerMeta:
 
 def _apply_flat(op, meta, cap, dy, *, params_sub, weights, norm_method,
                 conv_impl, embed_method="segsum", conv_norm="pe",
-                attn_norm="auto"):
+                attn_norm="ghost"):
     kind = meta.kind
     if kind == "dense" and not meta.segmented:
         if op == "pe_grad":

@@ -1,6 +1,7 @@
 """Per-example gradient strategies.
 
-The paper's three strategies plus the production extensions:
+The paper's three strategies materialize every per-example gradient; the
+tests use them as oracles:
 
   * ``naive`` — batch-size-1 loop (``lax.map``); the semantics oracle.
   * ``multi`` — ``vmap(grad)``: JAX's native realization of "B model copies
@@ -9,23 +10,25 @@ The paper's three strategies plus the production extensions:
     (via output taps), then per-layer reconstruction of per-example grads
     from (captured input, output cotangent) — outer products for dense
     layers, the grouped-convolution trick (Algorithms 1–2) for convs.
-  * ``ghost`` — per-example grad *norms* without materialization (Gram
-    trick) + a second, weighted backward pass.  O(1) extra memory.
-  * ``bk``    — "book-keeping": like ghost, but the clipped sum is formed
-    by weighted per-layer contractions from the captures already in hand —
-    no second backward.
-  * ``auto``  — the planned mixed pipeline: a cached per-layer execution
-    plan (:mod:`repro.core.costmodel`) chooses, for every tapped layer,
-    the cheapest exact norm realization (Gram ghost-norm — dense or
-    im2col'd conv — streamed materialization, rank-1, segsum) and the sum
-    phase (reuse grads the norm already materialized, book-keeping
-    contraction, or a shared weighted backward when contractions would
-    cost more than one extra backward).  The plan is keyed on (model,
-    batch/param shapes), so steady-state training runs exactly **one**
-    forward and **one** backward per step — no re-probe, no second
-    backward — vs the ghost path's two of each.  The plan's cost table is
-    also the seam future scaling work (sharding, microbatch schedules,
-    new layer kinds) plugs into.
+
+The other three execute a per-layer :class:`~repro.core.costmodel.ExecPlan`
+through one executor, :func:`planned_clipped_sum`: one capture backward,
+each parameter group's norm, the clip coefficients, then each group's
+share of the clipped sum.
+
+  * ``auto``  — the planner chooses, for every tapped layer, the cheapest
+    exact norm realization (Gram ghost-norm — dense or im2col'd conv —
+    streamed materialization, rank-1, segsum) and the sum phase (reuse
+    grads the norm already materialized, book-keeping contraction, or a
+    shared weighted backward when contractions would cost more than one
+    extra backward).  The plan is keyed on (model, batch/param shapes),
+    so steady-state training runs one forward and one backward per step
+    unless the plan takes the weighted backward.
+  * ``bk``    — "book-keeping": no stash; every group's sum is a weighted
+    contraction from the captures already in hand.
+  * ``ghost`` — no stash; every group's sum comes from one second,
+    weighted backward pass (two forwards and two backwards per step).
+    Flat clipping only.
 
 ``apply_fn(params, batch, tapper) -> (B,) per-example losses`` is the only
 contract a model must satisfy.  Execution counts (forwards / backwards /
@@ -43,8 +46,6 @@ on the axis it actually crosses.
 """
 from __future__ import annotations
 
-from collections import defaultdict
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -54,7 +55,8 @@ from repro.core import costmodel, kinds
 from repro.core.tapper import (STATS, Tapper, capture_backward, get_subtree,
                                probe, set_subtree)
 
-STRATEGIES = ("naive", "multi", "crb", "ghost", "bk", "auto")
+MATERIALIZING = ("naive", "multi", "crb")
+STRATEGIES = MATERIALIZING + costmodel.PLANNED_STRATEGIES
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +140,7 @@ def crb_per_example_grads(apply_fn, params, batch, *, conv_impl: str = "auto",
 
 
 # ---------------------------------------------------------------------------
-# ghost norms (shared by ghost & bk)
+# scopes of the private step's phases
 
 
 def group_key_of(path: tuple) -> str:
@@ -160,89 +162,6 @@ def phase_scope(phase: str, method: str | None = None,
     if path is not None:
         name += "/" + group_key_of(path).replace("/", ".")
     return jax.named_scope(name)
-
-
-def group_norms_from_captures(params, caps, dtaps, metas, *,
-                              norm_method: str = "auto",
-                              conv_impl: str = "auto",
-                              embed_method: str = "segsum",
-                              conv_norm: str = "auto",
-                              attn_norm: str = "auto"):
-    """Per-parameter-group per-example squared grad norms, grouping taps
-    that touch the same parameter (tied embeddings, shared blocks).
-
-    Returns ``(group_keys, norms)`` with ``norms`` of shape (G, B), in
-    sorted-path order — the same deterministic group order the planner's
-    :class:`~repro.core.costmodel.ExecPlan` uses, so per-layer clip
-    budgets resolved against either align."""
-    by_param = defaultdict(list)
-    for name, meta in metas.items():
-        by_param[meta.path].append(name)
-
-    # Segmented taps' leading axes are slots, not examples — the example
-    # count comes from their static metadata (same rule as _batch_size).
-    B = _batch_size(metas, dtaps)
-    keys, norms = [], []
-
-    def _method(names):
-        if len(names) == 1:
-            return "unplanned"
-        ks = sorted((metas[n].kind, metas[n].w_transposed) for n in names)
-        return "tied" if ks == [("dense", True), ("embed", False)] else "pe"
-
-    def _group_norm(path, names, method):
-        psub = get_subtree(params, path)
-        if method == "unplanned":
-            n = names[0]
-            return kinds.apply_kind(
-                "norm_sq", metas[n], caps[n], dtaps[n], params_sub=psub,
-                norm_method=norm_method, conv_impl=conv_impl,
-                embed_method=embed_method, conv_norm=conv_norm,
-                attn_norm=attn_norm)
-        if method == "tied":
-            # Tied embedding + LM head: per-tap norms plus the cross term.
-            n_e = next(n for n in names if metas[n].kind == "embed")
-            n_d = next(n for n in names if metas[n].kind == "dense")
-            n_g = kinds.apply_kind(
-                "norm_sq", metas[n_e], caps[n_e], dtaps[n_e], params_sub=psub,
-                embed_method=embed_method)
-            n_g = n_g + kinds.apply_kind(
-                "norm_sq", metas[n_d], caps[n_d], dtaps[n_d], params_sub=psub,
-                norm_method=norm_method)
-            return n_g + kinds.tied_embed_head_cross(
-                caps[n_e], dtaps[n_e], caps[n_d], dtaps[n_d])
-        # Generic exact fallback: materialize the summed per-example grad.
-        pe_sum: dict = {}
-        for n in names:
-            pe = kinds.apply_kind("pe_grad", metas[n], caps[n], dtaps[n],
-                                  params_sub=psub, conv_impl=conv_impl)
-            for k, v in pe.items():
-                pe_sum[k] = pe_sum[k] + v if k in pe_sum else v
-        return kinds._sumsq(pe_sum)
-
-    for path, names in sorted(by_param.items()):
-        keys.append(group_key_of(path))
-        method = _method(names)
-        with phase_scope("norm", method, path):
-            norms.append(tag(_group_norm(path, names, method),
-                             kind="group_norm", group=group_key_of(path),
-                             method=method, fused=False))
-    if not norms:
-        raise ValueError("no tapped layers")
-    return tuple(keys), jnp.stack(norms)
-
-
-def ghost_norms_from_captures(params, caps, dtaps, metas, **kw):
-    """Per-example squared norms of the *full* gradient (the flat-mode
-    total): sum of the per-group norms."""
-    _, norms = group_norms_from_captures(params, caps, dtaps, metas, **kw)
-    return jnp.sum(norms, axis=0)
-
-
-def ghost_norms(apply_fn, params, batch, **kw):
-    losses, caps, dtaps, metas = _capture(apply_fn, params, batch)
-    norms_sq = ghost_norms_from_captures(params, caps, dtaps, metas, **kw)
-    return losses, norms_sq, (caps, dtaps, metas)
 
 
 # ---------------------------------------------------------------------------
@@ -298,57 +217,35 @@ def clipped_grad_sum_detailed(apply_fn, params, batch, *, l2_clip: float,
                               norm_method: str = "auto",
                               conv_impl: str = "auto", check: bool = False,
                               embed_method: str = "segsum",
-                              conv_norm: str | None = None, overrides=None,
+                              conv_norm: str = "auto", overrides=None,
                               mem_budget: int | None = None, plan=None,
                               clip_policy=None, budgets=None,
-                              prev_norms_sq=None, attn_norm: str = "auto"):
+                              prev_norms_sq=None):
     """Returns (per-example losses, Σ_b clip(g_b), per-example norms²,
     detail).
 
-    ``conv_norm`` (auto | ghost | pe) picks the conv norm realization; the
-    historical ``None`` sentinel is a deprecated alias for ``"auto"`` (the
-    pre-engine ghost/bk default of materializing — ``"pe"`` — must now be
-    requested explicitly).  ``overrides`` pins individual layers by
-    tap-name glob (planned strategy only); ``plan`` injects a pre-built,
-    possibly deserialized ExecPlan, skipping the cached planner lookup.
+    ``naive``/``multi``/``crb`` materialize the per-example gradients and
+    clip flat.  ``ghost``/``bk``/``auto`` execute the plan
+    :func:`~repro.core.costmodel.get_plan` builds for these knobs (or
+    ``plan``, a pre-built, possibly deserialized ExecPlan) with
+    :func:`planned_clipped_sum`.  ``conv_norm`` (auto | ghost | pe |
+    pallas) picks the conv norm realization; ``overrides`` pins
+    individual layers by tap-name glob.
 
     ``clip_policy`` (a :class:`~repro.core.clipping.ClipPolicy`; None =
-    flat) selects the clipping mode; non-flat modes require the planned
-    (``auto``) or book-keeping (``bk``) strategy, whose coefficient flow
-    is per layer.  ``budgets`` injects a resolved (G,) per-layer budget
-    array (else the policy's static split is resolved against the sorted
-    group keys); ``prev_norms_sq`` feeds stale mode's lagged (B,) norms.
+    flat) selects the clipping mode of a planned strategy
+    (:class:`~repro.core.clipping.DPConfig` rejects a non-flat mode with
+    any strategy but ``auto`` and ``bk``).  ``budgets`` injects a
+    resolved (G,) per-layer budget array (else the policy's static split
+    is resolved against the sorted group keys); ``prev_norms_sq`` feeds
+    stale mode's lagged (B,) norms.
 
     ``detail``: ``group_keys`` (static tuple), ``group_norms_sq`` ((G, B)
     under per_layer, else None), ``coef`` (the applied coefficients —
     (B,) flat/stale, (G, B) per_layer), ``budgets`` ((G,) under
     per_layer, else None).
     """
-    mode = clip_policy.mode if clip_policy is not None else "flat"
-    if mode != "flat" and strategy not in ("auto", "bk"):
-        raise ValueError(
-            f"clipping mode {mode!r} requires strategy 'auto' or 'bk', "
-            f"got {strategy!r}")
-    if mode == "stale" and prev_norms_sq is None:
-        raise ValueError(
-            "stale clipping needs prev_norms_sq (the engine bootstraps "
-            "the first step with flat clipping and threads the state)")
-    if strategy == "auto":
-        if plan is None:
-            plan = costmodel.get_plan(
-                apply_fn, params, batch, norm_method=norm_method,
-                embed_method=embed_method, conv_norm=conv_norm or "auto",
-                conv_impl=conv_impl,
-                mem_budget=mem_budget or costmodel.STREAM_MEM_BUDGET,
-                overrides=overrides, clip_mode=mode,
-                clip_fused=(clip_policy.fused if clip_policy is not None
-                            else True))
-        return planned_clipped_sum(apply_fn, params, batch, plan,
-                                   l2_clip=l2_clip, conv_impl=conv_impl,
-                                   check=check, clip_policy=clip_policy,
-                                   budgets=budgets,
-                                   prev_norms_sq=prev_norms_sq)
-    if strategy in ("naive", "multi", "crb"):
+    if strategy in MATERIALIZING:
         if strategy == "naive":
             losses, pe = naive_per_example_grads(apply_fn, params, batch)
         elif strategy == "multi":
@@ -362,81 +259,54 @@ def clipped_grad_sum_detailed(apply_fn, params, batch, *, l2_clip: float,
             lambda g: jnp.einsum("b...,b->...", g.astype(jnp.float32), coef),
             pe)
         return losses, gsum, norms_sq, _flat_detail(coef)
+    mode = clip_policy.mode if clip_policy is not None else "flat"
+    if mode == "stale" and prev_norms_sq is None:
+        raise ValueError(
+            "stale clipping needs prev_norms_sq (the engine bootstraps "
+            "the first step with flat clipping and threads the state)")
+    if plan is None:
+        plan = costmodel.get_plan(
+            apply_fn, params, batch, norm_method=norm_method,
+            embed_method=embed_method, conv_norm=conv_norm,
+            conv_impl=conv_impl,
+            mem_budget=mem_budget or costmodel.STREAM_MEM_BUDGET,
+            overrides=overrides, clip_mode=mode,
+            clip_fused=(clip_policy.fused if clip_policy is not None
+                        else True), strategy=strategy)
+    return planned_clipped_sum(apply_fn, params, batch, plan,
+                               l2_clip=l2_clip, conv_impl=conv_impl,
+                               check=check, clip_policy=clip_policy,
+                               budgets=budgets,
+                               prev_norms_sq=prev_norms_sq)
 
-    losses, caps, dtaps, metas = _capture(apply_fn, params, batch)
-    group_keys, group_ns = group_norms_from_captures(
-        params, caps, dtaps, metas, norm_method=norm_method,
-        conv_impl=conv_impl, embed_method=embed_method,
-        conv_norm=conv_norm or "auto", attn_norm=attn_norm)
-    norms_sq = jnp.sum(group_ns, axis=0)
 
-    if mode == "per_layer":
-        if budgets is None:
-            from repro.core.clipping import resolve_budgets
-            budgets = resolve_budgets(clip_policy, l2_clip, group_keys)
-        coef = lax.stop_gradient(
-            per_layer_clip_coefficients(group_ns, budgets))      # (G, B)
-        detail = {"group_keys": group_keys, "group_norms_sq": group_ns,
-                  "coef": coef, "budgets": budgets}
-        gi_of = {k: i for i, k in enumerate(group_keys)}
-
-        def weight_of(meta):
-            return coef[gi_of[group_key_of(meta.path)]]
-    elif mode == "stale":
-        coef = lax.stop_gradient(
-            clip_coefficients(prev_norms_sq, l2_clip, mode="stale"))
-        detail = _flat_detail(coef)
-
-        def weight_of(meta):
-            return coef
-    else:
-        coef = lax.stop_gradient(clip_coefficients(norms_sq, l2_clip))
-        detail = _flat_detail(coef)
-
-        def weight_of(meta):
-            return coef
-
-    if strategy == "ghost":
-        def wloss(p):
-            losses2 = apply_fn(p, batch, Tapper())
-            return jnp.sum(losses2 * coef)
-
-        STATS.forwards += 1
-        STATS.backwards += 1
-        with phase_scope("contrib", "backward"):
-            gsum = jax.grad(wloss)(params)
-        return losses, gsum, norms_sq, detail
-
-    if strategy == "bk":
-        acc: dict = {}
-        for name, meta in metas.items():
-            with phase_scope("contrib", "contrib", meta.path):
-                contrib = kinds.apply_kind(
-                    "contrib", meta, caps[name], dtaps[name],
-                    params_sub=get_subtree(params, meta.path),
-                    weights=weight_of(meta), conv_impl=conv_impl)
-                _accumulate_param_grads(acc, meta.path, contrib)
-        gsum = _grads_to_tree(acc)
-        if check:
-            missing = check_coverage(params, gsum)
-            if missing:
-                raise ValueError(f"bk missing param contribs: {missing}")
-        return losses, gsum, norms_sq, detail
-
-    raise ValueError(f"unknown strategy {strategy!r}")
+def ghost_norms(apply_fn, params, batch, **norm_kw):
+    """Per-example squared norms of the full gradient, with no clipped sum:
+    the norm phase of the ``ghost`` plan for these knobs (those of
+    :func:`~repro.core.costmodel.get_plan`; ``attn_norm`` pins every
+    attention block's realization).  Returns (losses, (B,) norms²,
+    (captures, tap cotangents, layer metas))."""
+    attn_norm = norm_kw.pop("attn_norm", "auto")
+    plan = costmodel.get_plan(apply_fn, params, batch, strategy="ghost",
+                              **norm_kw)
+    if attn_norm != "auto":
+        pins = tuple((n, attn_norm) for n, lp in plan.layers.items()
+                     if lp.kind == "attn")
+        norm_kw["overrides"] = pins + costmodel.normalize_overrides(
+            norm_kw.get("overrides"))
+        plan = costmodel.get_plan(apply_fn, params, batch, strategy="ghost",
+                                  **norm_kw)
+    losses, caps, dtaps, metas = capture_backward(
+        apply_fn, params, batch, plan.make_taps(), with_metas=True)
+    conv_impl = norm_kw.get("conv_impl", "auto")
+    norms_sq = sum(_planned_group_norm(g, plan, metas, caps, dtaps, params,
+                                       conv_impl, {})
+                   for g in plan.groups)
+    return losses, norms_sq, (caps, dtaps, metas)
 
 
 # ---------------------------------------------------------------------------
-# The planned (mixed per-layer) pipeline: strategy="auto"
-
-
-def _batch_size(metas, dtaps):
-    for name, meta in metas.items():
-        if not meta.segmented:
-            return jax.tree.leaves(dtaps[name])[0].shape[meta.scanned]
-    for name, meta in metas.items():
-        return meta.static["n_examples"]
-    raise ValueError("no tapped layers")
+# The plan executor: strategies ghost, bk and auto
 
 
 def _norm_kwargs(lp):
@@ -641,12 +511,8 @@ def planned_clipped_sum(apply_fn, params, batch, plan, *, l2_clip: float,
             total = total + _stale_group_norm_contrib(
                 g, plan, metas, caps, dtaps, params, coef, conv_impl,
                 fused_ok, acc)
-        gsum = _grads_to_tree(acc)
-        if check:
-            missing = check_coverage(params, gsum)
-            if missing:
-                raise ValueError(f"auto missing param contribs: {missing}")
-        return losses, gsum, total, _flat_detail(coef)
+        return losses, _plan_sum(params, acc, check), total, \
+            _flat_detail(coef)
 
     stash: dict = {}
     group_ns = jnp.stack([
@@ -700,12 +566,16 @@ def planned_clipped_sum(apply_fn, params, batch, plan, *, l2_clip: float,
                     weights=w, conv_impl=conv_impl)
                 _accumulate_param_grads(acc, g.path, contrib)
 
+    return losses, _plan_sum(params, acc, check), total, detail
+
+
+def _plan_sum(params, acc, check):
     gsum = _grads_to_tree(acc)
     if check:
         missing = check_coverage(params, gsum)
         if missing:
-            raise ValueError(f"auto missing param contribs: {missing}")
-    return losses, gsum, total, detail
+            raise ValueError(f"plan missing param contribs: {missing}")
+    return gsum
 
 
 def per_example_grads(apply_fn, params, batch, strategy: str = "crb", **kw):

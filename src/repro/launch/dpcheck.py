@@ -52,8 +52,6 @@ def _build_engine(arch: str, clip_mode: str, mesh_spec, *,
     if dp_attn:
         cfg = cfg.replace(dp_attn=True)
     model = build_model(cfg)
-    if clip_mode != "flat" and strategy not in ("auto", "bk"):
-        strategy = "auto"
     dpc = DPConfig(l2_clip=clip, noise_multiplier=noise, strategy=strategy,
                    clipping=ClipPolicy(mode=clip_mode))
     mesh = None
@@ -90,9 +88,9 @@ def main(argv=None):
                          "(dp_attn=True) so attention lanes exercise the "
                          "attn ghost-norm path")
     ap.add_argument("--strategy", default="auto",
-                    help="per-example gradient strategy; 'auto' (default) "
-                         "exercises the planner so the plan/graph "
-                         "consistency pass has a plan to check")
+                    help="per-example gradient strategy (default auto); "
+                         "ghost, bk and auto execute a plan, which the "
+                         "plan/graph consistency pass checks")
     ap.add_argument("--coll-bytes-warn", type=int, default=None,
                     help="per-device collective-bytes warning threshold")
     ap.add_argument("--fail-on-warn", action="store_true",

@@ -381,7 +381,7 @@ def build_cell(arch: str, shape_name: str, mesh, *, microbatches=None,
     if shape.kind == "train":
         m = microbatches or (16 if cfg.fsdp else 8)
         dpkw = dict(l2_clip=1.0, noise_multiplier=1.0,
-                    strategy=cfg.dp_strategy, microbatches=m)
+                    strategy="auto", microbatches=m)
         normkw = dict(embed="gram")  # gram = paper-faithful baseline
         # --dp-set accepts both new NormCfg names (dense/embed/conv/
         # conv_impl) and the legacy knob names.

@@ -101,8 +101,8 @@ def main(argv=None) -> TrainRun:
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--clip", type=float, default=1.0)
     ap.add_argument("--noise", type=float, default=0.0)
-    ap.add_argument("--strategy", default=None,
-                    choices=[None, "naive", "multi", "crb", "ghost", "bk",
+    ap.add_argument("--strategy", default="auto",
+                    choices=["naive", "multi", "crb", "ghost", "bk",
                              "auto"])
     ap.add_argument("--clip-mode", default="flat",
                     choices=["flat", "per_layer", "stale"],
@@ -141,8 +141,8 @@ def main(argv=None) -> TrainRun:
     ap.add_argument("--mispredict-threshold", type=float, default=0.5,
                     help="relative measured-vs-predicted step time "
                          "divergence that triggers an automatic re-plan "
-                         "(requires an active calibration and planned "
-                         "execution, i.e. strategy auto); <= 0 disables")
+                         "(requires an active calibration and a planned "
+                         "strategy: ghost, bk or auto); <= 0 disables")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
@@ -180,15 +180,8 @@ def main(argv=None) -> TrainRun:
     if args.layers:
         cfg = cfg.replace(n_layers=args.layers)
     model = build_model(cfg)
-    # Non-flat clip modes need a per-group coefficient flow: respect an
-    # explicit --strategy (DPConfig validates the combination), but only
-    # override the model's configured default when it would be invalid.
-    strategy = args.strategy or cfg.dp_strategy
-    if args.clip_mode != "flat" and args.strategy is None \
-            and strategy not in ("auto", "bk"):
-        strategy = "auto"
     dpc = DPConfig(l2_clip=args.clip, noise_multiplier=args.noise,
-                   strategy=strategy,
+                   strategy=args.strategy,
                    microbatches=args.microbatches, delta=args.delta,
                    clipping=ClipPolicy(mode=args.clip_mode,
                                        budgets=args.clip_budgets))
@@ -254,9 +247,7 @@ def main(argv=None) -> TrainRun:
     if engine.calibration is not None:
         print(f"[calibrate] {engine.calibration.digest()} "
               f"(source={engine.calibration.source})")
-    # Fixed strategies bypass the planner; don't pay an advisory probe for
-    # them unless the user asks.
-    if args.explain or dpc.strategy == "auto":
+    if args.explain or dpc.planned:
         print(engine.explain())
     if args.explain:
         return TrainRun(engine, [], [])

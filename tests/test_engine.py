@@ -189,6 +189,44 @@ def test_auto_microbatches_divisor_selection():
     assert costmodel.auto_microbatches(plan, 8, mem_budget=1) == 8
 
 
+@pytest.mark.parametrize("strategy,sum_method", [("ghost", "backward"),
+                                                 ("bk", "contrib")])
+def test_ghost_and_bk_run_as_plans(strategy, sum_method):
+    """ghost and bk execute plans: no stash, every sum from the weighted
+    backward (ghost) or contracted (bk); the verifier checks the plan and
+    microbatches="auto" resolves through it."""
+    cfg = get_config("vgg16").replace(
+        cnn_arch="toy", cnn_channels=(4, 8), cnn_kernel=3, img_size=16,
+        n_classes=10)
+    model = build_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(0)
+    B = 4
+    batch = {"img": jnp.asarray(rng.randn(B, 3, 16, 16), jnp.float32),
+             "label": jnp.asarray(rng.randint(0, 10, (B,)), jnp.int32)}
+    engine = PrivacyEngine(model.apply, params, batch, run_seed=0,
+                           dp=DPConfig(l2_clip=1.0, noise_multiplier=1.0,
+                                       strategy=strategy))
+    plan = engine.plan()
+    assert set(plan.sum_methods().values()) == {sum_method}
+    assert not any(lp.stash for lp in plan.layers.values())
+    assert plan.needs_backward == (strategy == "ghost")
+    report = engine.verify()
+    assert report.ok, report.summary()
+    assert f"{len(plan.groups)} group realizations" in report.checked["plan"]
+
+    norm = NormCfg(mem_budget=1 << 12)   # tiny: forces a split
+    split = PrivacyEngine(model.apply, params, batch,
+                          dp=DPConfig(l2_clip=1.0, strategy=strategy,
+                                      microbatches="auto", norm=norm))
+    m = split.microbatches()
+    assert 1 < m == costmodel.auto_microbatches(split.plan(), B, 1 << 12)
+    _, grad, _ = split.noisy_grad(params, batch)
+    _, ref, _ = dp_gradient(model.apply, params, batch,
+                            cfg=DPConfig(l2_clip=1.0, strategy="multi"))
+    assert tree_maxdiff(grad, ref) < 1e-4
+
+
 # ---------------------------------------------------------------------------
 # Private steps
 

@@ -46,8 +46,7 @@ def test_arch_smoke(arch):
     batch = make_batch(cfg, rng)
     loss, grad, aux = dp_gradient(
         model.apply, params, batch,
-        cfg=DPConfig(l2_clip=1.0, noise_multiplier=0.0,
-                     strategy=cfg.dp_strategy),
+        cfg=DPConfig(l2_clip=1.0, noise_multiplier=0.0),
         key=jax.random.PRNGKey(1))
     assert np.isfinite(float(loss))
     assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grad))

@@ -36,17 +36,30 @@ def test_dense_gram_stream_crossover():
                                        mem_budget=1 << 20) == "gram"
 
 
+def _planned_conv_norm(side, C, D, k, B, **opts):
+    """The norm method the planner gives one stride-1 'same' conv whose
+    output is side x side."""
+    meta = LayerMeta("conv", ("conv",), static={
+        "stride": 1, "dilation": 1, "padding": k // 2, "groups": 1,
+        "kernel_shape": (D, C, k, k)})
+    f32 = jnp.float32
+    cap = {"x": jax.ShapeDtypeStruct((B, C, side, side), f32)}
+    dy = jax.ShapeDtypeStruct((B, D, side, side), f32)
+    plan = costmodel.plan_execution({"conv": meta}, {"conv": cap},
+                                    {"conv": dy}, lambda: {}, **opts)
+    return plan.layers["conv"].norm_method
+
+
 def test_conv_ghost_pe_crossover():
     # Early conv layer: large spatial output, few channels -> materialize
     # (the paper's Algorithm 2 regime).
-    assert costmodel.conv_norm_method(T=64 * 64, C=3, D=64, K=121, B=8) == "pe"
+    assert _planned_conv_norm(64, C=3, D=64, k=11, B=8) == "pe"
     # Late conv layer: tiny spatial output, wide channels -> im2col ghost
     # norm (the mixed-clipping regime of Bu et al.).
-    assert costmodel.conv_norm_method(T=4 * 4, C=512, D=512, K=9, B=8) \
-        == "ghost"
+    assert _planned_conv_norm(4, C=512, D=512, k=3, B=8) == "ghost"
     # Memory veto: pe scratch over budget falls back to the chunked ghost.
-    assert costmodel.conv_norm_method(T=64 * 64, C=256, D=512, K=9, B=64,
-                                      mem_budget=1 << 20) == "ghost"
+    assert _planned_conv_norm(64, C=256, D=512, k=3, B=64,
+                              mem_budget=1 << 20) == "ghost"
 
 
 def test_plan_is_mixed_on_toy_model(toy_model):
@@ -195,7 +208,7 @@ def test_auto_under_jit_and_microbatches(toy_model):
     from repro.core.clipping import dp_gradient
     apply_fn, params, batch = toy_model
     ref = dp_gradient(apply_fn, params, batch,
-                      cfg=DPConfig(l2_clip=0.1, strategy="bk"))
+                      cfg=DPConfig(l2_clip=0.1, strategy="multi"))
     dpc = DPConfig(l2_clip=0.1, strategy="auto", microbatches=2)
     loss, grad, aux = jax.jit(
         lambda p, b: dp_gradient(apply_fn, p, b, cfg=dpc))(params, batch)

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import ClipPolicy, DPConfig, PrivacyEngine
+from repro.core import ClipPolicy, DPConfig, PrivacyEngine, costmodel
 from repro.core.strategies import (clipped_grad_sum_detailed, group_key_of,
                                    phase_scope)
 from repro.models.registry import build_model
@@ -122,6 +122,9 @@ def test_compiled_step_carries_every_phase_scope(toy, mode):
     ("bk", "dp.contrib/contrib/conv1"),
 ])
 def test_unplanned_strategies_name_their_phases(toy, strategy, contrib):
+    """ghost and bk run as plans: each group's norm is scoped by its
+    planned method, and its sum by the weighted backward (ghost) or by
+    its contraction (bk)."""
     apply_fn, params, batch = toy
 
     def grad_sum(p, b):
@@ -129,8 +132,10 @@ def test_unplanned_strategies_name_their_phases(toy, strategy, contrib):
                                          strategy=strategy)[1]
 
     scopes = _compiled_scopes(grad_sum, params, batch)
-    assert {"dp.capture", "dp.clip", contrib,
-            "dp.norm/unplanned/conv0", "dp.norm/unplanned/fc0"} <= scopes
+    plan = costmodel.get_plan(apply_fn, params, batch, strategy=strategy)
+    want = {"dp.capture", "dp.clip", contrib} | _planned_scopes(plan, "flat")
+    assert want <= scopes, sorted(want - scopes)
+    assert {"dp.norm/pe/conv0", "dp.norm/rank1/fc0"} <= scopes
 
 
 def test_norm_scopes_agree_with_the_verifier_tags(toy):
